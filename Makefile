@@ -28,10 +28,10 @@ onboard-smoke:
 		--store-out /tmp/onboard_smoke_store.npz
 
 # 8-fake-device CPU mesh: serve + onboard must be BITWISE identical to the
-# 1-device path (the script forces its own device-count XLA flag). Not a
-# verify dep: the tier-1 suite (test_distributed) and bench-smoke
-# (serve_bench -> sharded.parity gate) already run the same vehicle — this
-# target is the standalone entry the CI multi-device job and humans use.
+# 1-device path and shard per-device bytes (the script forces its own
+# device-count XLA flag). Not a verify dep: the tier-1 suite
+# (test_distributed) already runs the same vehicle — this target is the
+# standalone entry the CI multi-device job and humans use.
 multidev-smoke:
 	$(PP) $(PY) benchmarks/sharded_smoke.py --check
 

@@ -276,9 +276,9 @@ def elastic(w: BenchWriter):
     from jax.sharding import NamedSharding, PartitionSpec
 
     from repro.distributed.fault import reshard_state, surviving_mesh
-    from repro.launch.mesh import make_mesh_compat
+    from repro.launch.mesh import make_mesh
 
-    mesh8 = make_mesh_compat((4, 2), ("data", "model"))
+    mesh8 = make_mesh((4, 2), ("data", "model"))
     state = {"a": jnp.arange(64.0).reshape(8, 8),
              "b": jnp.arange(16, dtype=jnp.int32)}
     sh8 = jax.tree.map(

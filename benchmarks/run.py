@@ -12,11 +12,12 @@ Prints ``name,us_per_call,derived`` CSV lines (plus per-table headers).
                                             emits BENCH_serve.json)
   train lifecycle   -> train_bench         (gang step + onboarding rate,
                                             emits BENCH_train.json)
-  dry-run roofline  -> roofline_report     (reads artifacts/dryrun)
+
+The dry-run roofline (``benchmarks/roofline_report.py``) reads artifacts
+that ``repro.launch.dryrun`` writes; run it on its own after a dry-run.
 """
 from __future__ import annotations
 
-import sys
 import time
 import traceback
 
@@ -24,6 +25,8 @@ import traceback
 def main() -> None:
     from benchmarks import (ablations, glue_sim, kernel_bench, serve_bench,
                             table1_memory, train_bench, train_time)
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     suites = [
         ("table1_memory", table1_memory.main),
         ("kernel_bench", kernel_bench.main),
@@ -44,15 +47,6 @@ def main() -> None:
             failures += 1
             traceback.print_exc()
             print(f"# {name} FAILED")
-    try:
-        import glob
-        if glob.glob("artifacts/dryrun/*.json"):
-            print("\n==== roofline_report (from artifacts/dryrun) ====")
-            from benchmarks import roofline_report
-            sys.argv = ["roofline_report", "--csv"]
-            roofline_report.main()
-    except Exception:  # noqa: BLE001
-        traceback.print_exc()
     if failures:
         raise SystemExit(1)
 

@@ -15,12 +15,6 @@ BENCH_serve.json (gated by benchmarks/check_bench.py):
 - decode.throughput           tokens/s with full slots
 - decode.host_syncs           host syncs per decoded token (< 1 with
                               sync_every > 1: device-resident decode state)
-- sharded.parity              8-fake-device mesh vs 1 device: graduated
-                              store bytes / admission Â/B̂ / decode tokens
-                              all BITWISE equal (subprocess, see
-                              benchmarks/sharded_smoke.py)
-- sharded.throughput          sharded-vs-single tokens/s + analytic
-                              per-device resident bytes under the mesh
 - cb.parity                   continuous-batching engine vs windowed on the
                               skewed workload: per-request token ids BITWISE
                               equal, decode step compiled exactly once
@@ -40,6 +34,10 @@ BENCH_serve.json (gated by benchmarks/check_bench.py):
                               ratio (tok/s floor under BENCH_STRICT only:
                               CPU toy shapes are compute-bound, see
                               benchmarks/spec_smoke.py)
+
+The mesh-vs-one-device parity lives in ``make multidev-smoke``
+(benchmarks/sharded_smoke.py --check): it needs its own process, and this
+one has already touched JAX.
 """
 from __future__ import annotations
 
@@ -340,25 +338,6 @@ def main(smoke: bool = False):
               for t, v in ht["record_bytes_per_type"].items()})
     w.emit("hetero.kernel_parity", None,
            **{t: int(ok) for t, ok in ht["kernel_parity"].items()})
-
-    # multi-device parity + throughput: subprocess (this process pinned
-    # itself to 1 CPU device at first jax use; the smoke forces 8 fake
-    # host devices and runs BOTH paths, so the record is self-contained)
-    from benchmarks.sharded_smoke import run_subprocess
-    sm = run_subprocess()
-    w.emit("sharded.parity", None, devices=sm["devices"], mesh=sm["mesh"],
-           onboard_store_bitwise_equal=sm["onboard_store_bitwise_equal"],
-           serve_entries_bitwise_equal=sm["serve_entries_bitwise_equal"],
-           decode_tokens_equal=sm["decode_tokens_equal"],
-           cb_decode_tokens_equal=sm["cb_decode_tokens_equal"],
-           cb_step_traces=sm["cb_step_traces"],
-           gang_traces=sm["gang_traces"])
-    w.emit("sharded.throughput", None,
-           single_tokens_per_s=sm["single"]["tokens_per_s"],
-           sharded_tokens_per_s=sm["sharded"]["tokens_per_s"],
-           sharded_vs_single=sm["sharded_vs_single"],
-           single_bytes_per_device=sm["single"]["resident_bytes_per_device"],
-           sharded_bytes_per_device=sm["sharded"]["resident_bytes_per_device"])
 
     w.write()
     return w.records

@@ -2,7 +2,7 @@
 
 Runs the SAME engine/trainer code twice — once unsharded, once on a
 (data=N/2, model=2) mesh over N forced host CPU devices (default N=8,
-i.e. 4x2) — and checks:
+i.e. 4x2; on an accelerator the N real devices instead) — and checks:
 
 - onboarding: the graduated `ProfileStore` records (packed mask bytes,
   fp16 LN affines) are byte-identical,
@@ -13,11 +13,11 @@ i.e. 4x2) — and checks:
               the slot axis, so the page-table gather/scatter never
               crosses a device boundary) with its step compiled once,
 
-plus throughput and the analytic per-device resident bytes for both
-paths. Prints ONE JSON line (the last stdout line) that serve_bench
-embeds into BENCH_serve.json and `benchmarks/check_bench.py` gates
-(parity mandatory; the sharded-vs-single throughput floor only under
-BENCH_STRICT=1 — 8 fake devices on one shared CPU are slower by design).
+- memory:     per-device resident bytes on the mesh strictly below the
+              single-device total (the mesh really shards state),
+
+plus throughput for both paths. Prints ONE JSON line (the last stdout
+line); ``--check`` exits 1 unless every bit above holds.
 
 Standalone (also how CI's multi-device job and tests/test_distributed.py
 invoke it):
@@ -44,9 +44,10 @@ def strip_device_count_flag(flags: str) -> str:
 
 def run_subprocess(*, check: bool = False, timeout: int = 1200) -> dict:
     """Run this smoke in a fresh subprocess and return its parsed JSON
-    record — the ONE entry point serve_bench and tests share (the smoke
-    must force its own device count before jax initializes, so it can
-    never run in an already-started jax process)."""
+    record — the entry point the tests use (the smoke must force its own
+    device count before jax initializes, so it can never run in an
+    already-started jax process — on a CPU host only: a chip belongs to
+    one process, so run the script itself there)."""
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     env = dict(os.environ)
     kept = strip_device_count_flag(env.get("XLA_FLAGS", ""))
@@ -71,7 +72,8 @@ def run_subprocess(*, check: bool = False, timeout: int = 1200) -> dict:
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--devices", type=int, default=8,
-                    help="forced host device count; the mesh is "
+                    help="forced host device count on a CPU host (an "
+                    "accelerator host uses all its devices); the mesh is "
                     "(devices/2, 2) over (data, model) and the roster/"
                     "slot count equals the data axis")
     ap.add_argument("--check", action="store_true",
@@ -82,19 +84,25 @@ def main():
 
     # must happen before the first jax import in this process; --devices is
     # authoritative (any inherited device-count token is replaced, other
-    # compiler flags carry over)
+    # compiler flags carry over). The flag only shapes the CPU backend:
+    # the platform is whatever JAX finds, so a chip host runs on its chips
     kept = strip_device_count_flag(os.environ.get("XLA_FLAGS", ""))
     want = f"--{_DEVICE_COUNT_FLAG}={args.devices}"
     os.environ["XLA_FLAGS"] = (kept + " " + want).strip()
-    os.environ.setdefault("JAX_PLATFORMS", "cpu")
 
     import numpy as np
     import jax
 
+    if jax.default_backend() != "cpu":
+        args.devices = jax.device_count()
+        if args.devices < 4 or args.devices % 2:
+            ap.error(f"{args.devices} {jax.default_backend()} devices: "
+                     "need an even number >= 4")
+
     from repro.configs import get_config, reduce_for_smoke
     from repro.core.profiles import ProfileStore
     from repro.data import MarkovLM
-    from repro.launch.mesh import make_mesh_compat
+    from repro.launch.mesh import make_mesh
     from repro.serve.engine import ServeEngine
     from repro.serve.scheduler import Request
     from repro.train import GraduationPolicy
@@ -103,7 +111,7 @@ def main():
     assert jax.device_count() >= args.devices, jax.device_count()
     cfg = reduce_for_smoke(get_config("qwen1.5-0.5b"))
     data_ax = args.devices // 2
-    mesh = make_mesh_compat((data_ax, 2), ("data", "model"))
+    mesh = make_mesh((data_ax, 2), ("data", "model"))
     mesh_str = f"{data_ax}x2:data,model"
     n_prof, slots = 4, data_ax
 
@@ -209,8 +217,9 @@ def main():
     }
     print(json.dumps(out))
     cb_ok = cb_tokens_ok and cb_tr1 == 1 and cb_tr8 == 1
+    mem_ok = 0 < bytes8["total"] < bytes1["total"]
     if args.check and not (onboard_ok and entries_ok and tokens_ok
-                           and cb_ok):
+                           and cb_ok and mem_ok):
         print("sharded_smoke: PARITY FAILED", file=sys.stderr)
         return 1
     return 0

@@ -5,15 +5,11 @@ import jax as _jax
 # threefry lowering, a jax.random draw whose consumer is GSPMD-sharded
 # produces DIFFERENT values than the same draw unsharded — the gang step's
 # Gumbel mask noise would silently diverge between 1 device and a mesh.
-# Partitionable threefry (the jax>=0.5 default) makes every draw a pure
-# function of (key, element index) regardless of partitioning, which the
-# multi-device parity gate (benchmarks/sharded_smoke.py) relies on.
-#
-# Deliberately set at PACKAGE import rather than per entry point: parity
-# needs the single-device and mesh paths of the SAME process to share one
-# RNG flavor, and a missed entry point would break bitwise parity silently.
-# The cost is a global-config side effect on hosts embedding repro as a
-# library on jax<0.5 — their own draws switch to the partitionable stream.
+# Partitionable threefry makes every draw a pure function of (key, element
+# index) regardless of partitioning, which the multi-device parity gate
+# (benchmarks/sharded_smoke.py) relies on. It is the installed JAX's
+# default; pinning it here keeps a host that flips the flag from breaking
+# bitwise parity silently.
 _jax.config.update("jax_threefry_partitionable", True)
 
 __version__ = "1.0.0"

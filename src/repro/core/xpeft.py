@@ -309,8 +309,10 @@ def precompute_effective_adapters_sparse(bank: dict, idx_a, w_a, idx_b, w_b,
 
     fia, fwa = flatten(idx_a, w_a)
     fib, fwb = flatten(idx_b, w_b)
-    a_hat = ops.mask_aggregate_batched(flat_a, fia, fwa, impl=xp.kernel_impl)
-    b_hat = ops.mask_aggregate_batched(flat_b, fib, fwb, impl=xp.kernel_impl)
+    a_hat = ops.mask_aggregate_batched(flat_a, fia, fwa, impl=xp.kernel_impl,
+                                       tp_dim=1)
+    b_hat = ops.mask_aggregate_batched(flat_b, fib, fwb, impl=xp.kernel_impl,
+                                       tp_dim=2)
     dt = bank["bank_a"].dtype
     return (a_hat.reshape(*batch, L, d, b).astype(dt),
             b_hat.reshape(*batch, L, b, d).astype(dt))

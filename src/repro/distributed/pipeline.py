@@ -15,7 +15,6 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 
 def pipeline_apply(stage_fn, stage_params, x_micro, mesh: Mesh,
@@ -36,10 +35,10 @@ def pipeline_apply(stage_fn, stage_params, x_micro, mesh: Mesh,
     pspec_x = P(None)  # microbatch stream replicated across stages
 
     @functools.partial(
-        shard_map, mesh=mesh,
+        jax.shard_map, mesh=mesh,
         in_specs=(pspec_params, pspec_x),
         out_specs=pspec_x,
-        check_rep=False)
+        check_vma=False)
     def run(params_local, xm):
         params_local = jax.tree.map(lambda a: a[0], params_local)
         idx = jax.lax.axis_index(axis)

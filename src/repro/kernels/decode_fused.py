@@ -34,6 +34,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.quant.schemes import dequant_block
 
@@ -70,6 +71,12 @@ def _norm_row(t, scale, bias, kind: str, eps: float = 1e-6):
             + bias.astype(jnp.float32)).astype(t.dtype)
 
 
+def _mm(a, b):
+    """a @ b in a's dtype, accumulated in f32 (the MXU's form; Mosaic
+    refuses a narrower accumulator)."""
+    return jnp.dot(a, b, preferred_element_type=jnp.float32).astype(a.dtype)
+
+
 def decode_block_row(x, pos, n1, n2, attn, mlp, kc, vc, ad, *, norm: str,
                      qkv_bias: bool, use_rope: bool, theta: float,
                      cap: float, mlp_type: str, act_name: str,
@@ -90,9 +97,9 @@ def decode_block_row(x, pos, n1, n2, attn, mlp, kc, vc, ad, *, norm: str,
 
     # --- norm1 + QKV (mirrors attention.attention at T=1) ----------------
     h = _norm_row(x, n1["scale"], n1.get("bias"), norm)
-    q = jnp.dot(h, attn["wq"].reshape(d, H * hd)).reshape(1, H, hd)
-    k = jnp.dot(h, attn["wk"].reshape(d, KV * hd)).reshape(1, KV, hd)
-    v = jnp.dot(h, attn["wv"].reshape(d, KV * hd)).reshape(1, KV, hd)
+    q = _mm(h, attn["wq"].reshape(d, H * hd)).reshape(1, H, hd)
+    k = _mm(h, attn["wk"].reshape(d, KV * hd)).reshape(1, KV, hd)
+    v = _mm(h, attn["wv"].reshape(d, KV * hd)).reshape(1, KV, hd)
     if qkv_bias:
         q = q + attn["bq"].astype(q.dtype)
         k = k + attn["bk"].astype(k.dtype)
@@ -101,7 +108,7 @@ def decode_block_row(x, pos, n1, n2, attn, mlp, kc, vc, ad, *, norm: str,
         half = hd // 2
         # == models.common.rope_freqs: iota*2.0 is exactly arange(0,hd,2)
         freqs = 1.0 / (theta ** (jax.lax.broadcasted_iota(
-            jnp.float32, (1, half), 1) * 2.0 / hd))
+            jnp.int32, (1, half), 1).astype(jnp.float32) * 2.0 / hd))
         ang = pos.astype(jnp.float32) * freqs            # [1, hd/2]
         cos = jnp.cos(ang)[:, None, :]
         sin = jnp.sin(ang)[:, None, :]
@@ -135,19 +142,20 @@ def decode_block_row(x, pos, n1, n2, attn, mlp, kc, vc, ad, *, norm: str,
     # causal+valid at T=1 collapse to k_pos <= pos
     logits = jnp.where(kp <= pos, logits, NEG_INF)
     w = jax.nn.softmax(logits, axis=-1)
-    o = jnp.einsum("kgts,ksh->kgth", w.astype(dt), vals)
+    o = jnp.einsum("kgts,ksh->kgth", w.astype(dt), vals,
+                   preferred_element_type=jnp.float32).astype(dt)
     o = o.transpose(2, 0, 1, 3).reshape(1, H * hd)
-    x = x + jnp.dot(o, attn["wo"].reshape(H * hd, d))
+    x = x + _mm(o, attn["wo"].reshape(H * hd, d))
 
     # --- norm2 + MLP ------------------------------------------------------
     h = _norm_row(x, n2["scale"], n2.get("bias"), norm)
     if mlp_type == "glu":
-        g = jnp.dot(h, mlp["wg"])
-        u = jnp.dot(h, mlp["wu"])
-        x = x + jnp.dot(act(g) * u, mlp["wd"])
+        g = _mm(h, mlp["wg"])
+        u = _mm(h, mlp["wu"])
+        x = x + _mm(act(g) * u, mlp["wd"])
     else:
-        m = act(jnp.dot(h, mlp["w1"]) + mlp["b1"].astype(h.dtype))
-        x = x + (jnp.dot(m, mlp["w2"]) + mlp["b2"].astype(h.dtype))
+        m = act(_mm(h, mlp["w1"]) + mlp["b1"].astype(h.dtype))
+        x = x + (_mm(m, mlp["w2"]) + mlp["b2"].astype(h.dtype))
 
     # --- X-PEFT adapter (same op order as the fused-adapter kernels) -----
     if adapter == "bf16":
@@ -233,15 +241,17 @@ def decode_block_pallas(x, pos, block, k_cache, v_cache, masks_l, *,
 
     def full(arr):
         nd = arr.ndim
-        return pl.BlockSpec(arr.shape, lambda bi, _n=nd: (0,) * _n)
+        return pl.BlockSpec(arr.shape, lambda bi, pos_ref, _n=nd: (0,) * _n)
 
     def row(arr):
         nd = arr.ndim
         return pl.BlockSpec((1,) + arr.shape[1:],
-                            lambda bi, _n=nd: (bi,) + (0,) * (_n - 1))
+                            lambda bi, pos_ref, _n=nd:
+                            (bi,) + (0,) * (_n - 1))
 
-    # (name, array, spec, leading-slot-dim?) in the kernel's fixed order
-    ins = [("x", x, row(x), True), ("pos", pos, row(pos), True)]
+    # (name, array, spec, leading-slot-dim?) in the kernel's fixed order;
+    # pos rides in SMEM (scalar prefetch): a (1,) block of [B] is refused
+    ins = [("x", x, row(x), True)]
     for nm in _weight_names(norm, qkv_bias, mlp_type):
         arr = _lookup(block, nm)
         ins.append((nm, arr, full(arr), False))
@@ -249,12 +259,20 @@ def decode_block_pallas(x, pos, block, k_cache, v_cache, masks_l, *,
     ins.append(("vc", v_cache, row(v_cache), True))
     for nm in ADAPTER_LEAVES[adapter]:
         arr = masks_l[nm]
+        if nm.endswith("_scale") and nm != "ln_scale":
+            arr = arr.astype(jnp.float32)     # Mosaic cannot load fp16
+        if arr.ndim == 2:
+            # per-slot vectors gain a unit axis ((1, n) blocks of [B, n]
+            # break the (8, 128) tiling rule): ln rows as [B, 1, b],
+            # int8 per-row scales as [B, n, 1] (dequant_block takes both)
+            arr = arr[..., None] if nm.endswith("_scale") and \
+                nm != "ln_scale" else arr[:, None, :]
         ins.append(("ad." + nm, arr, row(arr), True))
 
     names = tuple(nm for nm, _, _, _ in ins)
     rowset = tuple(is_row for _, _, _, is_row in ins)
 
-    def kernel(*refs):
+    def kernel(pos_ref, *refs):
         o_ref, k_ref, v_ref = refs[-3:]
         vals = {}
         for nm, is_row, ref in zip(names, rowset, refs[:-3]):
@@ -262,7 +280,8 @@ def decode_block_pallas(x, pos, block, k_cache, v_cache, masks_l, *,
         w = _regroup([n for n in names if "." in n],
                      [vals[n] for n in names if "." in n])
         y, k_row, v_row = decode_block_row(
-            vals["x"], vals["pos"], w["n1"], w["n2"], w["attn"], w["mlp"],
+            vals["x"], pos_ref[pl.program_id(0)], w["n1"], w["n2"],
+            w["attn"], w["mlp"],
             vals["kc"], vals["vc"], w.get("ad", {}), norm=norm,
             qkv_bias=qkv_bias, use_rope=use_rope, theta=theta, cap=cap,
             mlp_type=mlp_type, act_name=act_name, adapter=adapter,
@@ -271,19 +290,24 @@ def decode_block_pallas(x, pos, block, k_cache, v_cache, masks_l, *,
         k_ref[0] = k_row
         v_ref[0] = v_row
 
+    def row3(bi, pos_ref):
+        return bi, 0, 0
+
     return pl.pallas_call(
         kernel,
-        grid=(B,),
-        in_specs=[spec for _, _, spec, _ in ins],
-        out_specs=[
-            pl.BlockSpec((1, 1, d), lambda bi: (bi, 0, 0)),
-            pl.BlockSpec((1, KV, hd), lambda bi: (bi, 0, 0)),
-            pl.BlockSpec((1, KV, hd), lambda bi: (bi, 0, 0)),
-        ],
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(B,),
+            in_specs=[spec for _, _, spec, _ in ins],
+            out_specs=[
+                pl.BlockSpec((1, 1, d), row3),
+                pl.BlockSpec((1, KV, hd), row3),
+                pl.BlockSpec((1, KV, hd), row3),
+            ]),
         out_shape=[
             jax.ShapeDtypeStruct((B, 1, d), x.dtype),
             jax.ShapeDtypeStruct((B, KV, hd), cdt),
             jax.ShapeDtypeStruct((B, KV, hd), cdt),
         ],
         interpret=interpret,
-    )(*[arr for _, arr, _, _ in ins])
+    )(pos.astype(jnp.int32), *[arr for _, arr, _, _ in ins])

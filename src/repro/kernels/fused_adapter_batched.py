@@ -77,12 +77,14 @@ def fused_adapter_batched(x, a_hat, b_hat, ln_scale, ln_bias, *,
     shared_ln = ln_scale.ndim == 1
     if shared_proj:
         a_hat, b_hat = a_hat[None], b_hat[None]
-    if shared_ln:
-        ln_scale, ln_bias = ln_scale[None], ln_bias[None]
+    # ln rows as [B, 1, b]: a (1, b) block of a [B, b] array breaks the
+    # TPU's (8, 128) tiling rule; a unit second-minor axis does not
+    ln_scale = ln_scale.reshape(-1, 1, b)
+    ln_bias = ln_bias.reshape(-1, 1, b)
     row_p = (lambda bi, ti: (0, 0, 0)) if shared_proj else \
         (lambda bi, ti: (bi, 0, 0))
-    row_l = (lambda bi, ti: (0, 0)) if shared_ln else \
-        (lambda bi, ti: (bi, 0))
+    row_l = (lambda bi, ti: (0, 0, 0)) if shared_ln else \
+        (lambda bi, ti: (bi, 0, 0))
 
     kernel = functools.partial(_kernel, activation=activation, eps=1e-6,
                                use_ln=use_ln)
@@ -93,8 +95,8 @@ def fused_adapter_batched(x, a_hat, b_hat, ln_scale, ln_bias, *,
             pl.BlockSpec((1, block_t, d), lambda bi, ti: (bi, ti, 0)),
             pl.BlockSpec((1, d, b), row_p),
             pl.BlockSpec((1, b, d), row_p),
-            pl.BlockSpec((1, b), row_l),
-            pl.BlockSpec((1, b), row_l),
+            pl.BlockSpec((1, 1, b), row_l),
+            pl.BlockSpec((1, 1, b), row_l),
         ],
         out_specs=pl.BlockSpec((1, block_t, d), lambda bi, ti: (bi, ti, 0)),
         out_shape=jax.ShapeDtypeStruct((B, T, d), x.dtype),

@@ -65,14 +65,16 @@ def fused_adapter_quant_batched(x, a_q, a_scale, b_q, b_scale, ln_scale,
     def row3(bi, ti):
         return (bi, 0, 0)
 
-    def row2(bi, ti):
-        return (bi, 0)
-
-    scale_rank3 = scheme == "int4"
-    a_s_spec = (pl.BlockSpec((1, d, a_scale.shape[-1]), row3) if scale_rank3
-                else pl.BlockSpec((1, d), row2))
-    b_s_spec = (pl.BlockSpec((1, b, b_scale.shape[-1]), row3) if scale_rank3
-                else pl.BlockSpec((1, b), row2))
+    # Mosaic cannot load fp16 on the TPU: widen the scales to f32 here
+    # (exact, and dequant_block's first op anyway). Per-row vectors gain a
+    # unit axis: (1, d) / (1, b) blocks of [B, d] / [B, b] arrays break
+    # the TPU's (8, 128) tiling rule.
+    a_scale = a_scale.astype(jnp.float32)
+    b_scale = b_scale.astype(jnp.float32)
+    if scheme == "int8":
+        a_scale, b_scale = a_scale[..., None], b_scale[..., None]
+    ln_scale = ln_scale.reshape(B, 1, b)
+    ln_bias = ln_bias.reshape(B, 1, b)
     kernel = functools.partial(_kernel, scheme=scheme, activation=activation,
                                eps=1e-6)
     return pl.pallas_call(
@@ -81,11 +83,11 @@ def fused_adapter_quant_batched(x, a_q, a_scale, b_q, b_scale, ln_scale,
         in_specs=[
             pl.BlockSpec((1, block_t, d), lambda bi, ti: (bi, ti, 0)),
             pl.BlockSpec((1, d, a_q.shape[-1]), row3),
-            a_s_spec,
+            pl.BlockSpec((1, d, a_scale.shape[-1]), row3),
             pl.BlockSpec((1, b, b_q.shape[-1]), row3),
-            b_s_spec,
-            pl.BlockSpec((1, b), row2),
-            pl.BlockSpec((1, b), row2),
+            pl.BlockSpec((1, b, b_scale.shape[-1]), row3),
+            pl.BlockSpec((1, 1, b), row3),
+            pl.BlockSpec((1, 1, b), row3),
         ],
         out_specs=pl.BlockSpec((1, block_t, d), lambda bi, ti: (bi, ti, 0)),
         out_shape=jax.ShapeDtypeStruct((B, T, d), x.dtype),

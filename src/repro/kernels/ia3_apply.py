@@ -29,7 +29,7 @@ from jax.experimental import pallas as pl
 
 def _kernel(x_ref, s_ref, o_ref):
     x = x_ref[0]                                            # [block_t, d]
-    s = s_ref[0].astype(jnp.float32)                        # [d]
+    s = s_ref[0].astype(jnp.float32)                        # [1, d]
     y = x.astype(jnp.float32) * (1.0 + s)
     o_ref[0] = y.astype(x.dtype)
 
@@ -48,16 +48,18 @@ def ia3_apply_batched(x, s, *, block_t: int = 256, interpret: bool = False):
     block_t = _pick_block_t(T, block_t)
 
     shared = s.ndim == 1
-    if shared:
-        s = s[None]
-    row = (lambda bi, ti: (0, 0)) if shared else (lambda bi, ti: (bi, 0))
+    # s as [B, 1, d]: a (1, d) block of a [B, d] array breaks the TPU's
+    # (8, 128) tiling rule; a unit second-minor axis does not
+    s = s.reshape(-1, 1, d)
+    row = (lambda bi, ti: (0, 0, 0)) if shared else \
+        (lambda bi, ti: (bi, 0, 0))
 
     return pl.pallas_call(
         _kernel,
         grid=(B, T // block_t),
         in_specs=[
             pl.BlockSpec((1, block_t, d), lambda bi, ti: (bi, ti, 0)),
-            pl.BlockSpec((1, d), row),
+            pl.BlockSpec((1, 1, d), row),
         ],
         out_specs=pl.BlockSpec((1, block_t, d), lambda bi, ti: (bi, ti, 0)),
         out_shape=jax.ShapeDtypeStruct((B, T, d), x.dtype),

@@ -33,14 +33,14 @@ from repro.quant.schemes import check_scheme, dequant_block
 
 
 def _kernel(idx_ref, w_ref, q_ref, s_ref, out_ref, *, scheme):
-    ki = pl.program_id(2)
+    pi, ki = pl.program_id(0), pl.program_id(2)
 
     @pl.when(ki == 0)
     def _zero():
         out_ref[...] = jnp.zeros_like(out_ref)
 
     deq = dequant_block(q_ref[0], s_ref[0], scheme)     # [block_d, b] f32
-    out_ref[...] += w_ref[0, ki] * deq
+    out_ref[...] += w_ref[pi, ki] * deq
 
 
 @functools.partial(jax.jit,
@@ -59,23 +59,28 @@ def mask_aggregate_quant_batched(q, scale, idx, w, *, scheme: str,
     block_d = min(block_d, d)
     assert d % block_d == 0, (d, block_d)
 
-    scale_spec = (
-        pl.BlockSpec((1, block_d),
-                     lambda pi, di, ki, idx_ref: (idx_ref[pi, ki], di))
-        if scheme == "int8" else
-        pl.BlockSpec((1, block_d, scale.shape[-1]),
-                     lambda pi, di, ki, idx_ref: (idx_ref[pi, ki], di, 0)))
+    # Mosaic cannot load fp16 on the TPU: widen the scales to f32 here
+    # (exact, and dequant_block's first op anyway). int8's per-row scales
+    # go to [N, d, 1]: a (1, block_d) block of [N, d] breaks the (8, 128)
+    # tiling rule.
+    scale = scale.astype(jnp.float32)
+    if scheme == "int8":
+        scale = scale[..., None]
+
+    def bank_row(pi, di, ki, idx_ref, w_ref):
+        return idx_ref[pi, ki], di, 0
+
+    # the weights ride in SMEM beside the indices (as in
+    # mask_aggregate_batched)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
+        num_scalar_prefetch=2,
         grid=(P, d // block_d, k),
         in_specs=[
-            pl.BlockSpec((1, k), lambda pi, di, ki, idx_ref: (pi, 0)),
-            pl.BlockSpec((1, block_d, q.shape[-1]),
-                         lambda pi, di, ki, idx_ref: (idx_ref[pi, ki], di, 0)),
-            scale_spec,
+            pl.BlockSpec((1, block_d, q.shape[-1]), bank_row),
+            pl.BlockSpec((1, block_d, scale.shape[-1]), bank_row),
         ],
         out_specs=pl.BlockSpec((1, block_d, b),
-                               lambda pi, di, ki, idx_ref: (pi, di, 0)),
+                               lambda pi, di, ki, idx_ref, w_ref: (pi, di, 0)),
     )
     return pl.pallas_call(
         functools.partial(_kernel, scheme=scheme),

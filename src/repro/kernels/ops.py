@@ -26,6 +26,13 @@ payloads + fp16 scales and dequantize in-register inside the kernels
 (`mask_aggregate_quant.py`, `fused_adapter_quant.py`); the jnp refs share
 the exact dequant op sequence (`quant.schemes.dequant_block`).
 
+Meshes: GSPMD cannot partition a Mosaic kernel. Code traced under
+``kernel_mesh(mesh)`` (the serve engine's jitted steps, when it has a
+mesh) runs each kernel call as a ``shard_map``: per-row kernels split
+their rows over "data", the bank aggregation splits the bank's d_model
+axis over "model" (the layout ``distributed/sharding.py`` gives the bank),
+everything else is replicated inside the map.
+
 TPU deployment note: `bottleneck` b of 48/64 is below the 128 lane width;
 for peak MXU utilization pad Â/B̂'s b dim to 128 — LN must then mask the
 padded columns (ops here keep the unpadded semantics; the pad is a
@@ -33,8 +40,12 @@ launch-config choice).
 """
 from __future__ import annotations
 
+import contextlib
+from contextvars import ContextVar
+
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
 from repro.kernels import ref
 from repro.kernels.fused_adapter import fused_adapter as _fused_pallas
@@ -50,6 +61,56 @@ from repro.kernels.mask_aggregate_quant import (
 from repro.quant.schemes import check_scheme
 
 IMPLS = ("auto", "pallas", "interpret", "ref")
+
+_MESH: ContextVar = ContextVar("kernel_mesh", default=None)
+
+
+@contextlib.contextmanager
+def kernel_mesh(mesh):
+    """Trace the kernel calls inside under ``mesh`` (None: no mesh)."""
+    tok = _MESH.set(mesh)
+    try:
+        yield
+    finally:
+        _MESH.reset(tok)
+
+
+def _split(mesh, axis: str, size: int):
+    """``axis`` if the mesh has it and it divides ``size``, else None."""
+    n = dict(mesh.shape).get(axis)
+    return axis if n and size % n == 0 else None
+
+
+def _shard_map(kernel, args, in_specs, out_specs):
+    return jax.shard_map(kernel, mesh=_MESH.get(), in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)(*args)
+
+
+def _per_row(kernel, args, per_row):
+    """Run a per-row kernel (args[0] is x [B, ...]; ``per_row[i]`` says
+    whether args[i] carries the row axis): rows over "data" on a mesh."""
+    mesh = _MESH.get()
+    if mesh is None:
+        return kernel(*args)
+    lead = _split(mesh, "data", args[0].shape[0])
+    specs = tuple(P(lead) if row else P() for row in per_row)
+    return _shard_map(kernel, args, specs, P(lead))
+
+
+def _aggregate(kernel, banks, idx, w, tp_dim):
+    """Run a bank aggregation kernel(*banks, idx, w): on a mesh, bank row
+    axis ``tp_dim`` (the d_model axis, or None) over "model"; indices and
+    weights replicated. Rows aggregate independently, so each shard's
+    result is exact."""
+    mesh = _MESH.get()
+    if mesh is None:
+        return kernel(*banks, idx, w)
+    spec = [None] * banks[0].ndim
+    if tp_dim is not None:
+        spec[tp_dim] = _split(mesh, "model", banks[0].shape[tp_dim])
+    return _shard_map(kernel, (*banks, idx, w),
+                      tuple(P(*spec[:b.ndim]) for b in banks) + (P(), P()),
+                      P(*spec))
 
 
 def _on_tpu() -> bool:
@@ -73,12 +134,16 @@ def mask_aggregate(bank, idx, w, *, impl: str = "auto"):
     return _agg_pallas(bank, idx, w, interpret=impl == "interpret")
 
 
-def mask_aggregate_batched(bank, idx, w, *, impl: str = "auto"):
-    """bank [N,d,b], idx [P,k], w [P,k] -> [P,d,b] (single batched launch)."""
+def mask_aggregate_batched(bank, idx, w, *, impl: str = "auto",
+                           tp_dim=None):
+    """bank [N,d,b], idx [P,k], w [P,k] -> [P,d,b] (single batched launch).
+    ``tp_dim``: the bank row axis (1 or 2) that holds d_model, if any."""
     impl = resolve_impl(impl)
     if impl == "ref":
         return ref.mask_aggregate_batched_ref(bank, idx, w)
-    return _agg_pallas_batched(bank, idx, w, interpret=impl == "interpret")
+    return _aggregate(
+        lambda *a: _agg_pallas_batched(*a, interpret=impl == "interpret"),
+        (bank,), idx, w, tp_dim)
 
 
 def fused_adapter(x, a_hat, b_hat, ln_scale, ln_bias, *,
@@ -97,9 +162,13 @@ def fused_adapter(x, a_hat, b_hat, ln_scale, ln_bias, *,
             return ref.fused_adapter_batched_ref(
                 x, a_hat, b_hat, ln_scale, ln_bias, activation=activation,
                 use_ln=use_ln)
-        return _fused_pallas_batched(x, a_hat, b_hat, ln_scale, ln_bias,
-                                     activation=activation, use_ln=use_ln,
-                                     interpret=impl == "interpret")
+        return _per_row(
+            lambda *a: _fused_pallas_batched(
+                *a, activation=activation, use_ln=use_ln,
+                interpret=impl == "interpret"),
+            (x, a_hat, b_hat, ln_scale, ln_bias),
+            (True, a_hat.ndim == 3, b_hat.ndim == 3, ln_scale.ndim == 2,
+             ln_bias.ndim == 2))
     if impl == "ref":
         return ref.fused_adapter_ref(x, a_hat, b_hat, ln_scale, ln_bias,
                                      activation=activation, use_ln=use_ln)
@@ -134,7 +203,9 @@ def ia3_apply(x, s, *, impl: str = "auto"):
     if impl == "ref":
         out = ref.ia3_apply_batched_ref(x, s)
     else:
-        out = _ia3_pallas(x, s, interpret=impl == "interpret")
+        out = _per_row(
+            lambda *a: _ia3_pallas(*a, interpret=impl == "interpret"),
+            (x, s), (True, s.ndim == 2))
     return out[0] if squeeze else out
 
 
@@ -177,8 +248,12 @@ def mask_aggregate_quant_batched(q, scale, idx, w, *, scheme: str,
     if impl == "ref":
         return ref.mask_aggregate_quant_batched_ref(q, scale, idx, w,
                                                     scheme=scheme)
-    return _agg_pallas_quant(q, scale, idx, w, scheme=scheme,
-                             interpret=impl == "interpret")
+    # on a mesh the quantized rows replicate inside the map: int4 packs
+    # the B side's d_model axis planar, so a shard of it is not a slice
+    return _aggregate(
+        lambda *a: _agg_pallas_quant(*a, scheme=scheme,
+                                     interpret=impl == "interpret"),
+        (q, scale), idx, w, None)
 
 
 def fused_adapter_quant(x, a_q, a_scale, b_q, b_scale, ln_scale, ln_bias, *,
@@ -196,6 +271,8 @@ def fused_adapter_quant(x, a_q, a_scale, b_q, b_scale, ln_scale, ln_bias, *,
         return ref.fused_adapter_quant_batched_ref(
             x, a_q, a_scale, b_q, b_scale, ln_scale, ln_bias,
             scheme=scheme, activation=activation)
-    return _fused_pallas_quant(x, a_q, a_scale, b_q, b_scale, ln_scale,
-                               ln_bias, scheme=scheme, activation=activation,
-                               interpret=impl == "interpret")
+    return _per_row(
+        lambda *a: _fused_pallas_quant(*a, scheme=scheme,
+                                       activation=activation,
+                                       interpret=impl == "interpret"),
+        (x, a_q, a_scale, b_q, b_scale, ln_scale, ln_bias), (True,) * 7)

@@ -199,8 +199,6 @@ def lower_cell(arch: str, shape_name: str, multi_pod: bool,
 
     mem = compiled.memory_analysis()
     xla_cost = compiled.cost_analysis() or {}
-    if isinstance(xla_cost, (list, tuple)):  # older jax: [per-device dict]
-        xla_cost = xla_cost[0] if xla_cost else {}
     # XLA's HloCostAnalysis counts while bodies ONCE (verified); re-derive
     # flops/bytes/collectives with trip-count multiplication from the HLO.
     an = hlo_analyze(compiled.as_text())

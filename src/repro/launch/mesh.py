@@ -12,29 +12,22 @@ from __future__ import annotations
 import jax
 
 
-def make_mesh_compat(shape, axes):
-    """jax.make_mesh across jax versions.
-
-    ``axis_types`` (and ``jax.sharding.AxisType``) only exist from jax 0.5;
-    on older versions every axis is Auto-typed already, so the plain call is
-    equivalent. Everything in-repo that builds a mesh goes through here.
-    """
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return jax.make_mesh(shape, axes)
+def make_mesh(shape, axes):
+    """jax.make_mesh with every axis Auto-typed (GSPMD propagation).
+    Everything in-repo that builds a mesh goes through here."""
     return jax.make_mesh(shape, axes,
-                         axis_types=(axis_type.Auto,) * len(axes))
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return make_mesh_compat(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_test_mesh(shape=(2, 2), axes=("data", "model")):
     """Small mesh for unit tests (requires >= prod(shape) devices)."""
-    return make_mesh_compat(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def parse_mesh(spec: str):
@@ -56,4 +49,4 @@ def parse_mesh(spec: str):
     if len(shape) != len(axes):
         raise ValueError(f"--mesh {spec!r}: {len(shape)} dims for "
                          f"{len(axes)} axis names")
-    return make_mesh_compat(shape, axes)
+    return make_mesh(shape, axes)

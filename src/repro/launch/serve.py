@@ -19,6 +19,29 @@ import numpy as np
 import jax
 
 
+def build_engine(cfg, *, profiles: int, slots: int, max_seq: int,
+                 seed: int = 0, mesh=None, obs=None, **engine_kw):
+    """Random-init PLM + bank from ``seed``, ``profiles`` random profiles
+    in a ProfileStore, and a ServeEngine over them (``engine_kw`` pass
+    through: precompute, sync_every, cache_bytes, continuous, page_size,
+    ...). The one assembly this launcher and ``chip_smoke.py`` share."""
+    from repro.core import xpeft as XP
+    from repro.core.profiles import ProfileStore
+    from repro.models import init_lm
+    from repro.serve.engine import ServeEngine
+
+    key = jax.random.key(seed)
+    params = init_lm(key, cfg)
+    xp = cfg.xpeft
+    store = ProfileStore(cfg.num_layers, xp.num_adapters, xp.bottleneck,
+                         xp.mask_type, xp.k)
+    table = XP.init_profile_table(key, cfg)
+    for pid in range(profiles):
+        store.add_profile(pid, jax.tree.map(lambda t: t[pid], table))
+    return ServeEngine(cfg, params, store, max_slots=slots, max_seq=max_seq,
+                       mesh=mesh, obs=obs, **engine_kw)
+
+
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen1.5-0.5b")
@@ -44,34 +67,23 @@ def main():
     args = ap.parse_args()
 
     from repro.configs import get_config, reduce_for_smoke
-    from repro.core import xpeft as XP
-    from repro.core.profiles import ProfileStore
+    from repro.launch.compile_cache import enable_compile_cache
     from repro.launch.mesh import parse_mesh
-    from repro.models import init_lm
-    from repro.serve.engine import Request, ServeEngine
+    from repro.serve.engine import Request
 
+    enable_compile_cache()
     mesh = parse_mesh(args.mesh)
     cfg = get_config(args.arch)
     if args.smoke:
         cfg = reduce_for_smoke(cfg)
-    key = jax.random.key(0)
-    params = init_lm(key, cfg)
-
-    xp = cfg.xpeft
-    store = ProfileStore(cfg.num_layers, xp.num_adapters, xp.bottleneck,
-                         xp.mask_type, xp.k)
-    table = XP.init_profile_table(key, cfg)
-    for pid in range(args.profiles):
-        store.add_profile(pid, jax.tree.map(lambda t: t[pid], table))
-    print(f"profiles: {args.profiles} x {store.bytes_per_profile()} B each "
-          f"(masks, byte-level)")
-
     obs = OBS.from_cli_args(args)
-    eng = ServeEngine(cfg, params, store, max_slots=args.slots,
-                      max_seq=args.max_seq,
-                      precompute=not args.no_precompute,
-                      sync_every=args.sync_every,
-                      cache_bytes=args.cache_mb << 20, mesh=mesh, obs=obs)
+    eng = build_engine(cfg, profiles=args.profiles, slots=args.slots,
+                       max_seq=args.max_seq, mesh=mesh, obs=obs,
+                       precompute=not args.no_precompute,
+                       sync_every=args.sync_every,
+                       cache_bytes=args.cache_mb << 20)
+    print(f"profiles: {args.profiles} x {eng.store.bytes_per_profile()} B "
+          f"each (masks, byte-level)")
     if mesh is not None:
         rb = eng.resident_bytes_per_device()
         print(f"mesh {dict(mesh.shape)}: {rb['total']} resident B/device "

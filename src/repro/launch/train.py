@@ -13,22 +13,13 @@ converged profiles into a serving ProfileStore:
       --store-out /tmp/profiles.npz --ckpt-dir /tmp/ck
 
 --smoke uses the reduced config (CPU-runnable); the full config is for real
-accelerators. On TPU pods also pass --mesh to enable pjit sharding, plus the
-latency-hiding scheduler flags below (LIBTPU_INIT_ARGS).
+accelerators. On several chips also pass --mesh to enable pjit sharding.
 """
 from __future__ import annotations
 
 import argparse
 
 import jax
-
-# XLA flags a real TPU deployment ships with (documented here; harmless on CPU)
-TPU_PERF_FLAGS = (
-    "--xla_tpu_enable_latency_hiding_scheduler=true "
-    "--xla_tpu_megacore_fusion_allow_ags=true "
-    "--xla_enable_async_all_gather=true "
-    "--xla_enable_async_collective_permute=true"
-)
 
 
 def run_onboarding(args):
@@ -141,6 +132,8 @@ def main():
     OBS.add_cli_args(ap)  # --metrics-json PATH, --trace PATH
     args = ap.parse_args()
 
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     if args.onboard:
         run_onboarding(args)
         return

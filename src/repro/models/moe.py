@@ -116,7 +116,6 @@ def aux_loss(probs, topi, E):
 # (pinned to the ff dim by the sharding rules).
 
 def _moe_shard_map(params, x, cfg, mesh):
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     ba = tuple(a for a in ("pod", "data") if a in mesh.shape)
@@ -176,9 +175,10 @@ def _moe_shard_map(params, x, cfg, mesh):
         aux = jax.lax.pmean(aux, ba) if ba else aux
         return out.reshape(B, T, d), aux
 
-    fn = shard_map(inner, mesh=mesh,
-                   in_specs=(P(None, None), wg_spec, wg_spec, wd_spec, x_spec),
-                   out_specs=(x_spec, P()),
-                   check_rep=False)
+    fn = jax.shard_map(inner, mesh=mesh,
+                       in_specs=(P(None, None), wg_spec, wg_spec, wd_spec,
+                                 x_spec),
+                       out_specs=(x_spec, P()),
+                       check_vma=False)
     return fn(params["router"], params["ew_g"], params["ew_u"],
               params["ew_d"], x)

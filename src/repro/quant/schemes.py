@@ -84,9 +84,11 @@ def pack_int4(q):
 
 
 def unpack_int4(packed):
-    """uint8 [..., n/2] -> int32 [..., n] in [-8, 7] (planar layout)."""
-    lo = (packed & 0xF).astype(jnp.int32) - INT4_BIAS
-    hi = (packed >> 4).astype(jnp.int32) - INT4_BIAS
+    """uint8 [..., n/2] -> int32 [..., n] in [-8, 7] (planar layout).
+    Widens before shifting: Mosaic has no 8-bit shift on the TPU."""
+    p = packed.astype(jnp.int32)
+    lo = (p & 0xF) - INT4_BIAS
+    hi = (p >> 4) - INT4_BIAS
     return jnp.concatenate([lo, hi], axis=-1)
 
 
@@ -109,19 +111,30 @@ def quantize_int4(x, *, group: int = 32) -> dict:
 # ----------------------------------------------------------------------------
 
 def dequant_block(q, scale, scheme: str):
-    """Dequantize one block to fp32. int8: q [..., n] with scale [...];
-    int4: packed q [..., n/2] with scale [..., n/g]. The op sequence here
-    is the single source of truth for every backend (Pallas compiled,
-    Pallas interpret, jnp ref), which is what makes them bit-identical."""
+    """Dequantize one block to fp32. int8: q [..., n] with scale [...] (or
+    [..., 1], the form the TPU kernels' VMEM tiling wants); int4: packed
+    q [..., n/2] with scale [..., n/g]. The op sequence here is the single
+    source of truth for every backend (Pallas compiled, Pallas interpret,
+    jnp ref), which is what makes them bit-identical."""
     if scheme == "int8":
-        return q.astype(jnp.float32) * scale.astype(jnp.float32)[..., None]
+        s = scale.astype(jnp.float32)
+        if s.ndim < q.ndim:
+            s = s[..., None]
+        return q.astype(jnp.float32) * s
     if scheme == "int4":
         vals = unpack_int4(q).astype(jnp.float32)
-        groups = scale.shape[-1]
-        n = vals.shape[-1]
-        vg = vals.reshape(vals.shape[:-1] + (groups, n // groups))
-        vg = vg * scale.astype(jnp.float32)[..., None]
-        return vg.reshape(vals.shape)
+        s = scale.astype(jnp.float32)
+        groups = s.shape[-1]
+        g = vals.shape[-1] // groups
+        # widen the group scales to one per element by selects, not by a
+        # [..., groups, g] reshape (which Mosaic cannot lay out); each
+        # element still takes exactly one f32 product with its scale
+        gid = jax.lax.broadcasted_iota(jnp.int32, vals.shape,
+                                       vals.ndim - 1) // g
+        wide = jnp.broadcast_to(s[..., :1], vals.shape)
+        for j in range(1, groups):
+            wide = jnp.where(gid == j, s[..., j:j + 1], wide)
+        return vals * wide
     raise ValueError(f"dequant_block: scheme {scheme!r}")
 
 

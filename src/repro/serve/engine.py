@@ -55,6 +55,7 @@ from repro import obs as OBS
 from repro.obs import trace as TR
 from repro.core import xpeft as XP
 from repro.core.profiles import ProfileStore
+from repro.kernels import ops
 from repro.models import model as MDL
 from repro.resilience import (InjectedHydrationError, RecordIntegrityError,
                               RetryPolicy, retry_with_backoff)
@@ -111,6 +112,16 @@ class ServeEngine:
         # correction/bonus token. Greedy output is bitwise identical to
         # non-speculative greedy per request; speculation only changes how
         # many device steps the same tokens take.
+        if cfg.decode_fused and \
+                ops.resolve_impl(cfg.xpeft.kernel_impl) == "pallas":
+            raise ValueError(
+                "decode_fused cannot be served with compiled Pallas: the "
+                "megakernel's row math reshapes the [1, H*hd] QKV rows to "
+                "[1, H, hd], which Mosaic cannot lay out on the TPU, and "
+                "it holds a whole layer's weights in VMEM (~25 MB at "
+                "qwen1.5-0.5b widths, over v5e's 16 MiB scoped default). "
+                "Serve with decode_fused=False, or kernel_impl='ref' / "
+                "'interpret' to run the megakernel math")
         self.spec = bool(cfg.spec_enable)
         self.spec_gamma = int(cfg.spec_gamma)
         if self.spec:
@@ -493,6 +504,7 @@ class ServeEngine:
                 return greedy_next(MDL.lm_logits(params, hidden, cfg)), \
                     cache
 
+        decode_fn = self._on_mesh(decode_fn)
         self.slots = SlotState(max_slots, max_seq, sync_every, decode_fn,
                                mesh=mesh,
                                cache_shardings=self._shardings.get("cache"),
@@ -512,7 +524,7 @@ class ServeEngine:
             return self._prefill_impl(params, tokens, masks, lengths,
                                       cache_pos, prefix_rows)
 
-        self._prefill = jax.jit(_prefill_traced)
+        self._prefill = jax.jit(self._on_mesh(_prefill_traced))
         # the cache/mask buffers round-trip through these every wave: pin
         # their out-shardings so placement never drifts (a drift would both
         # retrace the decode step and migrate the KV cache mid-serve)
@@ -554,23 +566,23 @@ class ServeEngine:
         # Hetero banks swap in the per-type bucketing aggregation (same
         # kernels, one launch per typed segment) returning the entry dict.
         if self.hetero:
-            self._aggregate_sparse = jax.jit(
+            self._aggregate_sparse = jax.jit(self._on_mesh(
                 lambda bank, ia, wa, ib, wb:
                 XP.precompute_effective_adapters_sparse_hetero(
-                    bank, ia, wa, ib, wb, xp))
+                    bank, ia, wa, ib, wb, xp)))
         else:
-            self._aggregate_sparse = jax.jit(
+            self._aggregate_sparse = jax.jit(self._on_mesh(
                 lambda bank, ia, wa, ib, wb:
                 XP.precompute_effective_adapters_sparse(
-                    bank, ia, wa, ib, wb, xp))
+                    bank, ia, wa, ib, wb, xp)))
         self._aggregate_dense = jax.jit(
             XP.precompute_effective_adapters_dense_batched)
         if self.quant != "none":
             from repro.quant import schemes as QS
-            self._aggregate_sparse_quant = jax.jit(
+            self._aggregate_sparse_quant = jax.jit(self._on_mesh(
                 lambda qbank, ia, wa, ib, wb:
                 XP.precompute_effective_adapters_sparse_quant(
-                    qbank, ia, wa, ib, wb, xp))
+                    qbank, ia, wa, ib, wb, xp)))
             # re-quantize freshly aggregated fp32 rows into the cache/slot
             # record layout (per-row over the last axis, like the bank)
             def _requant(a_hat, b_hat):
@@ -639,6 +651,18 @@ class ServeEngine:
         self._win_t0 = time.perf_counter()  # host time the window opened
 
     # ------------------------------------------------------------- jit impls
+    def _on_mesh(self, fn):
+        """``fn`` traced under the engine's mesh for the kernel layer:
+        GSPMD cannot partition a Mosaic kernel, so on a mesh every kernel
+        call inside runs as a shard_map (kernels/ops.py)."""
+        if self.mesh is None:
+            return fn
+
+        def traced(*args, **kw):
+            with ops.kernel_mesh(self.mesh):
+                return fn(*args, **kw)
+        return traced
+
     def _prefill_impl(self, params, tokens, masks, lengths, cache_pos=None,
                       prefix_rows=None):
         """Batched prefill of one length bucket: tokens [B, pad], per-request

@@ -143,3 +143,14 @@ def test_megakernel_ineligible_shapes_compose(setup):
     assert _decode_fused_route(cfg, None, True, 1) == "none"  # bare PLM
     off = cfg.with_(decode_fused=False)
     assert _decode_fused_route(off, masks, True, 1) is None
+
+
+def test_megakernel_refused_with_compiled_pallas(setup):
+    """The megakernel does not compile for the TPU at real widths, so an
+    engine asked to serve it through compiled Pallas refuses, saying why,
+    instead of falling back to the composed path in silence."""
+    cfg, params, store = setup
+    cfg = cfg.with_(decode_fused=True).with_xpeft(kernel_impl="pallas")
+    with pytest.raises(ValueError, match="decode_fused .*Mosaic"):
+        ServeEngine(cfg, params, store, max_slots=2, max_seq=64,
+                    continuous=True, page_size=16)

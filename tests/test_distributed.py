@@ -122,7 +122,7 @@ def test_quant_engine_qbank_and_buffers_shard():
     table = XP.init_profile_table(key, cfg)
     for pid in range(4):
         store.add_profile(pid, jax.tree.map(lambda t: t[pid], table))
-    mesh = make_mesh_compat((4, 2), ("data", "model"))
+    mesh = make_mesh((4, 2), ("data", "model"))
     eng = ServeEngine(cfg, params, store, max_slots=8, max_seq=32,
                       mesh=mesh)
     reqs = [Request(uid=i, prompt=np.arange(5) % cfg.vocab_size,
@@ -206,7 +206,7 @@ sys.path.insert(0, "src")
 import numpy as np
 import jax, jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
-from repro.launch.mesh import make_mesh_compat
+from repro.launch.mesh import make_mesh
 """
 
 
@@ -225,12 +225,11 @@ def _run_sub(body: str):
 def test_compressed_psum_numerics():
     _run_sub("""
     from functools import partial
-    from jax.experimental.shard_map import shard_map
     from repro.distributed.collectives import compressed_psum, compressed_psum_ef
-    mesh = make_mesh_compat((8,), ("d",))
+    mesh = make_mesh((8,), ("d",))
     x = jax.random.normal(jax.random.key(0), (8, 64))
 
-    @partial(shard_map, mesh=mesh, in_specs=P("d", None), out_specs=P("d", None))
+    @partial(jax.shard_map, mesh=mesh, in_specs=P("d", None), out_specs=P("d", None))
     def f(xl):
         return compressed_psum(xl, "d")
     got = f(x)[0]
@@ -239,7 +238,7 @@ def test_compressed_psum_numerics():
     assert err < 0.05, err
 
     # error feedback: mean of quantized psums over repeated steps converges
-    @partial(shard_map, mesh=mesh, in_specs=(P("d", None), P("d", None)),
+    @partial(jax.shard_map, mesh=mesh, in_specs=(P("d", None), P("d", None)),
              out_specs=(P("d", None), P("d", None)))
     def g(xl, el):
         y, e = compressed_psum_ef(xl, el, "d")
@@ -258,7 +257,7 @@ def test_compressed_psum_numerics():
 def test_pipeline_matches_single_device():
     _run_sub("""
     from repro.distributed.pipeline import pipeline_apply, stack_stages
-    mesh = make_mesh_compat((4, 2), ("pod", "model"))
+    mesh = make_mesh((4, 2), ("pod", "model"))
     L, d = 8, 16
     ks = jax.random.split(jax.random.key(0), L)
     layers = jax.vmap(lambda k: {"w": jax.random.normal(k, (d, d)) / np.sqrt(d)})(ks)
@@ -307,7 +306,7 @@ def test_small_mesh_train_step_and_moe_parity():
     step = make_train_step(cfg, "xpeft", lr=1e-3)
     s1, m1 = jax.jit(step)(state, batch, jax.random.key(7))
 
-    mesh = make_mesh_compat((4, 2), ("data", "model"))
+    mesh = make_mesh((4, 2), ("data", "model"))
     with ctx.mesh_context(mesh):
         st_sh = to_shardings(param_specs(state, mesh, fsdp=True), mesh)
         b_sh = to_shardings(batch_specs(batch, mesh, B), mesh)
@@ -335,8 +334,8 @@ def test_eight_device_serve_onboard_bitwise_parity():
     BIT-identically to the 1-device path — graduated store bytes, admission
     Â/B̂ cache entries, and decoded token ids all equal, with the gang step
     tracing exactly once on both paths. Runs benchmarks/sharded_smoke.py
-    (the same vehicle serve_bench embeds into BENCH_serve.json) through its
-    shared subprocess entry point."""
+    (the same vehicle as `make multidev-smoke`) through its shared
+    subprocess entry point."""
     from benchmarks.sharded_smoke import run_subprocess
 
     rec = run_subprocess(check=True)
@@ -349,11 +348,43 @@ def test_eight_device_serve_onboard_bitwise_parity():
     assert 0 < sharded < single  # the mesh actually shards device state
 
 
+def test_mesh_engine_kernels_run_under_shard_map():
+    """GSPMD cannot partition a Mosaic kernel: on a mesh the engine traces
+    its steps under ops.kernel_mesh, so each kernel call (here the exact
+    kernel bodies, interpret mode) runs as a shard_map — rows over "data",
+    the bank's d_model over "model". Decoded tokens and admission
+    aggregates must equal the single-device engine's."""
+    _run_sub("""
+    from repro.configs import get_config, reduce_for_smoke
+    from repro.launch.serve import build_engine
+    from repro.serve.engine import Request
+    cfg = reduce_for_smoke(get_config("qwen1.5-0.5b")).with_xpeft(
+        kernel_impl="interpret")
+    mesh = make_mesh((4, 2), ("data", "model"))
+
+    def serve(mesh_):
+        eng = build_engine(cfg, profiles=3, slots=4, max_seq=64, mesh=mesh_,
+                           continuous=True, page_size=16)
+        reqs = [Request(uid=i, prompt=np.arange(3 + 2 * i) % cfg.vocab_size,
+                        profile_id=i % 3, max_new_tokens=5)
+                for i in range(6)]
+        eng.run_until_drained(reqs)
+        assert eng.serve_stats()["step_traces"] == 1
+        return ([list(r.generated) for r in reqs],
+                np.asarray(eng.profile_cache.peek(0)["a_hat"]))
+
+    toks1, a1 = serve(None)
+    toks8, a8 = serve(mesh)
+    assert toks1 == toks8, (toks1, toks8)
+    np.testing.assert_allclose(a8, a1, rtol=0, atol=1e-6)
+    """)
+
+
 def test_elastic_reshard_smaller_mesh():
     _run_sub("""
     from repro.distributed.fault import reshard_state, surviving_mesh
     from jax.sharding import NamedSharding
-    mesh8 = make_mesh_compat((8,), ("data",))
+    mesh8 = make_mesh((8,), ("data",))
     x = jax.device_put(jnp.arange(64.0).reshape(8, 8),
                        NamedSharding(mesh8, P("data", None)))
     mesh4 = surviving_mesh(("data",), (8,), "data", 4)

@@ -174,7 +174,7 @@ def test_elastic_shrink_resumes_onboarding(tmp_path):
     from repro.data import MarkovLM
     from repro.distributed import sharding as SH
     from repro.distributed.fault import reshard_state, surviving_mesh
-    from repro.launch.mesh import make_mesh_compat
+    from repro.launch.mesh import make_mesh
     from repro.train import GraduationPolicy
     from repro.train.onboarding import build_onboarding_run
 
@@ -194,7 +194,7 @@ def test_elastic_shrink_resumes_onboarding(tmp_path):
         return trainer
 
     # reference: unfailed straight-through run on the full mesh
-    mesh8 = make_mesh_compat((4, 2), ("data", "model"))
+    mesh8 = make_mesh((4, 2), ("data", "model"))
     ref = build(mesh8)
     ref.run_until_drained(max_steps=200)
     assert len(ref.scheduler.graduated) == n_prof

@@ -59,10 +59,7 @@ def test_xla_cost_analysis_undercounts_scans():
         return y
 
     c = jax.jit(f).lower(x, x).compile()
-    ca = c.cost_analysis()
-    if isinstance(ca, list):  # older jax returns [per-device dict]
-        ca = ca[0]
-    xla_flops = ca["flops"]
+    xla_flops = c.cost_analysis()["flops"]
     ours = analyze(c.as_text())["flops"]
     assert ours > 5 * xla_flops  # XLA reports ~1 body; we report 9
 
@@ -79,8 +76,8 @@ def test_collectives_counted(tmp_path):
         import jax, jax.numpy as jnp
         from jax.sharding import PartitionSpec as P, NamedSharding
         from repro.analysis.hlo_cost import analyze
-        from repro.launch.mesh import make_mesh_compat
-        mesh = make_mesh_compat((8,), ("d",))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((8,), ("d",))
         w = jax.ShapeDtypeStruct((512, 512), jnp.float32)
         x = jax.ShapeDtypeStruct((512, 512), jnp.float32)
         sh_w = NamedSharding(mesh, P("d", None))

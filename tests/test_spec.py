@@ -149,7 +149,7 @@ def test_spec_mesh_bitwise_parity():
     from repro.configs import get_config, reduce_for_smoke
     from repro.core import xpeft as XP
     from repro.core.profiles import ProfileStore
-    from repro.launch.mesh import make_mesh_compat
+    from repro.launch.mesh import make_mesh
     from repro.models import init_lm
     from repro.serve.engine import ServeEngine
     from benchmarks.cb_smoke import skewed_requests
@@ -162,7 +162,7 @@ def test_spec_mesh_bitwise_parity():
     table = XP.init_profile_table(key, cfg)
     for pid in range(3):
         store.add_profile(pid, jax.tree.map(lambda t: t[pid], table))
-    mesh = make_mesh_compat((4, 2), ("data", "model"))
+    mesh = make_mesh((4, 2), ("data", "model"))
     out = {}
     for gamma in (0, 3):
         c = cfg.with_(spec_enable=gamma > 0, spec_gamma=max(gamma, 1))
