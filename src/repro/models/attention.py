@@ -120,6 +120,29 @@ def _sdpa_chunked(q, k, v, q_pos, k_pos, *, causal, window, kv_valid, scale,
     return outs.transpose(1, 2, 3, 0, 4, 5).reshape(B, KV, G, Tq, dv)
 
 
+@jax.named_scope("kv_cache_update")
+def _cache_update(cache, k, v, cache_pos):
+    """Write the new positions' K/V rows into the dense cache at
+    ``cache_pos`` (a scalar offset, or one per row)."""
+    if jnp.ndim(cache_pos) == 0:
+        ck = jax.lax.dynamic_update_slice_in_dim(
+            cache["k"], k.astype(cache["k"].dtype), cache_pos, axis=1)
+        cv = jax.lax.dynamic_update_slice_in_dim(
+            cache["v"], v.astype(cache["v"].dtype), cache_pos, axis=1)
+        return ck, cv
+    # per-slot positions (continuous batching / speculative verify):
+    # scatter T consecutive steps at each slot's own offset; writes past S
+    # fall off the end and are dropped (the engine masks those slots via
+    # kv_valid and never commits their tokens)
+    B, T = k.shape[:2]
+    idx = cache_pos[:, None] + jnp.arange(T)
+    ck = cache["k"].at[jnp.arange(B)[:, None], idx].set(
+        k.astype(cache["k"].dtype), mode="drop")
+    cv = cache["v"].at[jnp.arange(B)[:, None], idx].set(
+        v.astype(cache["v"].dtype), mode="drop")
+    return ck, cv
+
+
 def attention(params, x, *, positions, cfg, cache=None, cache_pos=None,
               is_global=True, q_chunk=512, k_chunk=1024, extra_kv=None,
               front_skip=None):
@@ -172,21 +195,7 @@ def attention(params, x, *, positions, cfg, cache=None, cache_pos=None,
 
     k_idx = None
     if cache is not None:
-        if jnp.ndim(cache_pos) == 0:
-            ck = jax.lax.dynamic_update_slice_in_dim(
-                cache["k"], k.astype(cache["k"].dtype), cache_pos, axis=1)
-            cv = jax.lax.dynamic_update_slice_in_dim(
-                cache["v"], v.astype(cache["v"].dtype), cache_pos, axis=1)
-        else:
-            # per-slot positions (continuous batching / speculative verify):
-            # scatter T consecutive steps at each slot's own offset; writes
-            # past S fall off the end and are dropped (the engine masks
-            # those slots via kv_valid and never commits their tokens)
-            idx = cache_pos[:, None] + jnp.arange(T)
-            ck = cache["k"].at[jnp.arange(B)[:, None], idx].set(
-                k.astype(cache["k"].dtype), mode="drop")
-            cv = cache["v"].at[jnp.arange(B)[:, None], idx].set(
-                v.astype(cache["v"].dtype), mode="drop")
+        ck, cv = _cache_update(cache, k, v, cache_pos)
         new_cache = {"k": ck, "v": cv}
         # quantized caches (e.g. f8) cast back to compute dtype on read
         keys, vals = ck.astype(k.dtype), cv.astype(v.dtype)

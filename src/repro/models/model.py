@@ -240,16 +240,17 @@ def _decode_fused_apply(block, x, masks_l, cfg, *, positions, cache_l,
         act_name=cfg.act, adapter=route,
         adapter_act=cfg.xpeft.adapter_activation,
         impl=cfg.xpeft.kernel_impl)
-    if jnp.ndim(cache_pos) == 0:
-        ck = jax.lax.dynamic_update_slice_in_dim(
-            cache_l["k"], k_rows[:, None], cache_pos, axis=1)
-        cv = jax.lax.dynamic_update_slice_in_dim(
-            cache_l["v"], v_rows[:, None], cache_pos, axis=1)
-    else:
-        ck = cache_l["k"].at[jnp.arange(B), cache_pos].set(
-            k_rows, mode="drop")
-        cv = cache_l["v"].at[jnp.arange(B), cache_pos].set(
-            v_rows, mode="drop")
+    with jax.named_scope("kv_cache_update"):
+        if jnp.ndim(cache_pos) == 0:
+            ck = jax.lax.dynamic_update_slice_in_dim(
+                cache_l["k"], k_rows[:, None], cache_pos, axis=1)
+            cv = jax.lax.dynamic_update_slice_in_dim(
+                cache_l["v"], v_rows[:, None], cache_pos, axis=1)
+        else:
+            ck = cache_l["k"].at[jnp.arange(B), cache_pos].set(
+                k_rows, mode="drop")
+            cv = cache_l["v"].at[jnp.arange(B), cache_pos].set(
+                v_rows, mode="drop")
     return y, {"k": ck, "v": cv}
 
 
@@ -320,7 +321,8 @@ def _make_body(cfg, positions, cache_pos, use_cache, fused_route=None):
                 block, x, cfg, positions=positions, cache_l=cache_l,
                 cache_pos=cache_pos, is_global=is_global, extra_kv=extra_kv,
                 front_skip=front_skip)
-        x = _xpeft_apply(x, bank_l, masks_l, cfg)
+        with jax.named_scope("adapter"):
+            x = _xpeft_apply(x, bank_l, masks_l, cfg)
         # re-pin the residual stream each layer (Megatron-SP: under
         # act_rules {"seq": "model"} the scan carry — and therefore the
         # remat-saved layer inputs — stay sequence-sharded over TP)

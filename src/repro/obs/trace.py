@@ -1,12 +1,23 @@
-"""Span tracer emitting Chrome-trace-event JSON (open in Perfetto /
+"""The program's one span system: every span is a
+``jax.profiler.TraceAnnotation`` on the profiler's clock, and, when the
+bundle is enabled, also a Chrome-trace-event record in a ring buffer
+(``--trace PATH`` of the launchers exports it; open in Perfetto /
 ``chrome://tracing``).
 
 Spans cover the host-side orchestration the aggregate counters can't
-explain: admission waves, prefill buckets, decode windows, preempt/resume,
-spec draft/verify rounds, gang steps, graduation, degraded/quarantine
-events. Nothing here ever touches the device — a span brackets work the
-host was already doing, so tracing changes no compiled program and no
-sync schedule.
+explain: admission waves and their parts (probe, hydrate, aggregate, mask
+scatter, prefill), the decode sync and its parts (fetch, distribute,
+window refresh), preempt/resume, spec rounds, gang-step flushes, the
+onboarding poll and graduation, degraded/quarantine events. Nothing here
+ever touches the device: a span brackets work the host was already
+doing, so tracing changes no compiled program and no sync schedule.
+
+The annotation is always opened, whether or not the bundle is enabled
+(``NULL_OBS`` included): with the profiler off it is an inert native
+object (under a microsecond), with it on the span lands on the host
+plane of any ``jax.profiler`` capture beside the device's ops. Args are
+attached only while a capture runs. Every span name starts with
+``serve.`` or ``train.``.
 
 The ring buffer is bounded (``deque(maxlen=capacity)``): leaving the
 tracer on forever costs a fixed few MB and drops the OLDEST events, never
@@ -20,10 +31,12 @@ from __future__ import annotations
 
 import json
 import os
+import re
 import time
 from collections import deque
-from contextlib import contextmanager
 from typing import Dict, Optional
+
+from jax.profiler import TraceAnnotation
 
 # Canonical categories. Emitters may use others, but these are the lanes
 # the obs smoke asserts are present end-to-end.
@@ -35,6 +48,21 @@ CAT_SPEC = "spec"
 CAT_GANG_STEP = "gang-step"
 CAT_GRADUATION = "graduation"
 CAT_RESILIENCE = "resilience"
+
+# span names start with one of these, so no name of the program's can
+# equal one a caller (a benchmark harness) writes around it
+PREFIXES = ("serve.", "train.")
+
+
+def _profiler_args(args: dict) -> dict:
+    """Args as the profiler's metadata takes them: numbers and bools as
+    they are, anything else as a string without the encoding's ``,#=``."""
+    out = {}
+    for k, v in args.items():
+        if not isinstance(v, (bool, int, float)):
+            v = re.sub(r"[,#=]", " ", str(v))
+        out[k] = v
+    return out
 
 
 class SpanTracer:
@@ -60,28 +88,20 @@ class SpanTracer:
             self.dropped += 1
         self._events.append(ev)
 
-    @contextmanager
-    def span(self, cat: str, name: str, **args):
-        """Complete-event ("X") span around a host-side block. Yields the
-        args dict so the body can attach results (e.g. admitted count)."""
-        if not self.enabled:
-            yield args
-            return
-        t0 = self.clock()
-        try:
-            yield args
-        finally:
-            t1 = self.clock()
-            self._emit({"name": name, "cat": cat, "ph": "X",
-                        "ts": t0 * 1e6, "dur": (t1 - t0) * 1e6,
-                        "pid": self._pid, "tid": self._tid(cat),
-                        "args": args})
+    def span(self, cat: str, name: str, **args) -> "_Span":
+        """A span around a host-side block (``with tracer.span(...) as
+        args:``): always a profiler annotation, and a complete-event ("X")
+        record when enabled. Yields the args dict so the body can attach
+        results (e.g. admitted count); the profiler gets them as the
+        span's metadata at its end."""
+        return _Span(self, cat, name, args)
 
     def complete(self, cat: str, name: str, t0: float, t1: float,
                  **args) -> None:
         """Retroactive "X" span over [t0, t1] (same clock as `span`) — for
         intervals whose start predates the emit site, e.g. a decode window
-        opened by the previous sync."""
+        opened by the previous sync. Ring buffer only: the profiler takes
+        no span after the fact."""
         if not self.enabled:
             return
         self._emit({"name": name, "cat": cat, "ph": "X", "ts": t0 * 1e6,
@@ -90,7 +110,11 @@ class SpanTracer:
 
     def instant(self, cat: str, name: str, **args) -> None:
         """Zero-duration marker ("i") for point events (degraded request,
-        quarantine, retry, graduation)."""
+        quarantine, retry, preemption): a profiler annotation of no
+        length, and a record when enabled."""
+        on = TraceAnnotation.is_enabled()
+        with TraceAnnotation(name, **(_profiler_args(args) if on else {})):
+            pass
         if not self.enabled:
             return
         self._emit({"name": name, "cat": cat, "ph": "i", "s": "t",
@@ -122,6 +146,34 @@ class SpanTracer:
     def reset(self) -> None:
         self._events.clear()
         self.dropped = 0
+
+
+class _Span:
+    """One open span of a ``SpanTracer`` (a plain class: a generator-based
+    context manager costs several times the annotation itself)."""
+
+    __slots__ = ("tracer", "cat", "name", "args", "tm", "t0")
+
+    def __init__(self, tracer: SpanTracer, cat: str, name: str, args: dict):
+        self.tracer, self.cat, self.name, self.args = tracer, cat, name, args
+
+    def __enter__(self) -> dict:
+        self.tm = TraceAnnotation(self.name)
+        self.tm.__enter__()
+        self.t0 = self.tracer.clock() if self.tracer.enabled else 0.0
+        return self.args
+
+    def __exit__(self, *exc) -> None:
+        tr = self.tracer
+        t1 = tr.clock() if tr.enabled else 0.0
+        if self.args and TraceAnnotation.is_enabled():
+            self.tm.set_metadata(**_profiler_args(self.args))
+        self.tm.__exit__(None, None, None)
+        if tr.enabled:
+            tr._emit({"name": self.name, "cat": self.cat, "ph": "X",
+                      "ts": self.t0 * 1e6, "dur": (t1 - self.t0) * 1e6,
+                      "pid": tr._pid, "tid": tr._tid(self.cat),
+                      "args": self.args})
 
 
 def validate_chrome_trace(doc: dict) -> Optional[str]:

@@ -808,7 +808,7 @@ class ServeEngine:
         self.slot_degraded[slot] = False
         r.preemptions += 1
         self.preemptions += 1
-        self.obs.tracer.instant(TR.CAT_PREEMPT, "preempt", slot=slot,
+        self.obs.tracer.instant(TR.CAT_PREEMPT, "serve.preempt", slot=slot,
                                 uid=r.uid)
         self.obs.metrics.inc("serve.preemptions")
 
@@ -869,8 +869,8 @@ class ServeEngine:
             self.slot_degraded[slot] = snap["degraded"]
             self._slot_seq[slot] = snap["seq"]
             self.resumes += 1
-            self.obs.tracer.instant(TR.CAT_PREEMPT, "resume", slot=slot,
-                                    uid=r.uid)
+            self.obs.tracer.instant(TR.CAT_PREEMPT, "serve.resumed",
+                                    slot=slot, uid=r.uid)
             self.obs.metrics.inc("serve.resumes")
             n += 1
         return n
@@ -970,8 +970,9 @@ class ServeEngine:
             self.obs.metrics.inc("serve.hydration_retries")
             self.obs.metrics.observe("serve.hydration_retry_delay_us",
                                      delay * 1e6, "us")
-            self.obs.tracer.instant(TR.CAT_RESILIENCE, "hydration_retry",
-                                    profile=pid, attempt=a)
+            self.obs.tracer.instant(TR.CAT_RESILIENCE,
+                                    "serve.hydration_retry", profile=pid,
+                                    attempt=a)
 
         try:
             retry_with_backoff(probe, policy=self.retry_policy,
@@ -993,7 +994,7 @@ class ServeEngine:
                 r.degraded = True
                 self.degraded_requests += 1
                 self.obs.metrics.inc("serve.degraded_requests")
-                self.obs.tracer.instant(TR.CAT_RESILIENCE, "degraded",
+                self.obs.tracer.instant(TR.CAT_RESILIENCE, "serve.degraded",
                                         profile=pid, uid=r.uid)
 
     # ------------------------------------------------------------- hydration
@@ -1022,6 +1023,8 @@ class ServeEngine:
             self.last_admission = {"path": "per_step", "requests": R,
                                    "cache_hits": 0,
                                    "cache_misses": len(ok_idx),
+                                   "missed_profiles": len(
+                                       {pids[i] for i in ok_idx}),
                                    "degraded": R - len(ok_idx),
                                    "bank_bytes_per_request": 0}
             return {key: jnp.stack([row[key] for row in rows])
@@ -1068,13 +1071,15 @@ class ServeEngine:
             if self.store.mask_type == "hard":
                 # k-sparse fast path: only the top-k bank rows are read
                 ia, wa, ib, wb = self.store.batch_sparse_indices(missing)
-                pad_i = jnp.zeros((Mp - M,) + ia.shape[1:], ia.dtype)
-                pad_w = jnp.zeros((Mp - M,) + wa.shape[1:], wa.dtype)
-                agg = self._aggregate_sparse(
-                    bank, jnp.concatenate([ia, pad_i]),
-                    jnp.concatenate([wa, pad_w]),
-                    jnp.concatenate([ib, pad_i]),
-                    jnp.concatenate([wb, pad_w]))
+                with self.obs.tracer.span(TR.CAT_ADMISSION, "serve.aggregate",
+                                          profiles=M, padded=Mp):
+                    pad_i = jnp.zeros((Mp - M,) + ia.shape[1:], ia.dtype)
+                    pad_w = jnp.zeros((Mp - M,) + wa.shape[1:], wa.dtype)
+                    agg = self._aggregate_sparse(
+                        bank, jnp.concatenate([ia, pad_i]),
+                        jnp.concatenate([wa, pad_w]),
+                        jnp.concatenate([ib, pad_i]),
+                        jnp.concatenate([wb, pad_w]))
                 if not self.hetero:
                     agg = {"a_hat": agg[0], "b_hat": agg[1]}
                 k = ia.shape[-1]
@@ -1099,10 +1104,12 @@ class ServeEngine:
                 # reads the bank once per call, amortized over the batch
                 # (hetero precompute serving is hard-mask only — ctor)
                 wa, wb, ln_s, ln_b = self.store.batch_mask_weights(missing)
-                pad_w = jnp.zeros((Mp - M,) + wa.shape[1:], wa.dtype)
-                a_hat, b_hat = self._aggregate_dense(
-                    bank, jnp.concatenate([wa, pad_w]),
-                    jnp.concatenate([wb, pad_w]))
+                with self.obs.tracer.span(TR.CAT_ADMISSION, "serve.aggregate",
+                                          profiles=M, padded=Mp):
+                    pad_w = jnp.zeros((Mp - M,) + wa.shape[1:], wa.dtype)
+                    a_hat, b_hat = self._aggregate_dense(
+                        bank, jnp.concatenate([wa, pad_w]),
+                        jnp.concatenate([wb, pad_w]))
                 agg = {"a_hat": a_hat, "b_hat": b_hat}
                 path = "dense"
                 bank_bytes = N * L * slice_bytes
@@ -1134,6 +1141,10 @@ class ServeEngine:
         self.last_admission = {
             "path": path, "requests": R, "cache_hits": hits,
             "cache_misses": misses, "unique_profiles": len(set(pids)),
+            # distinct profiles the cache did not hold: the aggregation the
+            # wave needed (aggregated_profiles is that count padded to a
+            # power of two)
+            "missed_profiles": len(missing),
             "aggregated_profiles": aggregated,
             "degraded": sum(r.degraded for r in reqs),
             "bank_bytes_per_request": bank_bytes // R}
@@ -1184,14 +1195,16 @@ class ServeEngine:
                 Mp = pow2_count(M)
                 aggregated = Mp
                 ia, wa, ib, wb = self.store.batch_sparse_indices(agg_pids)
-                pad_i = jnp.zeros((Mp - M,) + ia.shape[1:], ia.dtype)
-                pad_w = jnp.zeros((Mp - M,) + wa.shape[1:], wa.dtype)
-                a_hat, b_hat = self._aggregate_sparse_quant(
-                    self.qbank, jnp.concatenate([ia, pad_i]),
-                    jnp.concatenate([wa, pad_w]),
-                    jnp.concatenate([ib, pad_i]),
-                    jnp.concatenate([wb, pad_w]))
-                q = self._requantize(a_hat, b_hat)
+                with self.obs.tracer.span(TR.CAT_ADMISSION, "serve.aggregate",
+                                          profiles=M, padded=Mp):
+                    pad_i = jnp.zeros((Mp - M,) + ia.shape[1:], ia.dtype)
+                    pad_w = jnp.zeros((Mp - M,) + wa.shape[1:], wa.dtype)
+                    a_hat, b_hat = self._aggregate_sparse_quant(
+                        self.qbank, jnp.concatenate([ia, pad_i]),
+                        jnp.concatenate([wa, pad_w]),
+                        jnp.concatenate([ib, pad_i]),
+                        jnp.concatenate([wb, pad_w]))
+                    q = self._requantize(a_hat, b_hat)
                 k = ia.shape[-1]
                 # TRUE quantized row bytes actually streamed from HBM
                 bank_bytes = Mp * k * L * self._qrow_bytes
@@ -1225,6 +1238,7 @@ class ServeEngine:
         self.last_admission = {
             "path": path, "requests": R, "cache_hits": hits,
             "cache_misses": misses, "unique_profiles": len(set(pids)),
+            "missed_profiles": len(missing),
             "aggregated_profiles": aggregated,
             "store_hydrated_profiles": store_hydrated,
             "scheme": self.quant,
@@ -1249,10 +1263,18 @@ class ServeEngine:
         """Admit up to len(free_slots()) requests: one cache-aware batched
         hydration, one mask scatter, one prefill per length bucket, one
         slot-state scatter. Returns #admitted."""
-        with self.obs.tracer.span(TR.CAT_ADMISSION, "admit_wave",
+        with self.obs.tracer.span(TR.CAT_ADMISSION, "serve.admit_wave",
                                   offered=len(reqs)) as sp:
+            before = self.last_admission
             n = self._admit_wave(reqs)
             sp["admitted"] = n
+            adm = self.last_admission
+            if n and adm is not None and adm is not before:
+                sp.update(uids=" ".join(str(r.uid) for r in reqs[:n]),
+                          hits=adm["cache_hits"],
+                          missed=adm["missed_profiles"],
+                          aggregated=adm.get("aggregated_profiles", 0),
+                          path=adm["path"])
         return n
 
     def _admit_wave(self, reqs: List[Request]) -> int:
@@ -1261,7 +1283,8 @@ class ServeEngine:
             self.sync()  # flush the window before touching slot state
         resumed = 0
         if self.continuous and self._resume_q:
-            resumed = self._try_resume()  # preempted work outranks fresh
+            with self.obs.tracer.span(TR.CAT_PREEMPT, "serve.resume"):
+                resumed = self._try_resume()  # preempted work outranks fresh
         free = self.free_slots()
         if len(reqs) > len(free):
             # the caller sized the wave to the PRE-sync free count; the
@@ -1276,6 +1299,7 @@ class ServeEngine:
                 self._refresh_window()  # resumed slots need window + view
             return 0
         assigned = free[:len(reqs)]
+        self.scheduler.record_wait(reqs, t_wave)
         if self.continuous:
             # commit page/entry tables BEFORE the prefill insert and mask
             # scatter — both address device memory through them
@@ -1296,8 +1320,10 @@ class ServeEngine:
             # health-probe every profile first (with retry): requests whose
             # profile can't be hydrated degrade to the bare PLM below,
             # never failing the wave for their healthy peers
-            self._probe_wave(reqs)
-        stacked = self._hydrate_stacked(reqs)
+            with self.obs.tracer.span(TR.CAT_ADMISSION, "serve.probe"):
+                self._probe_wave(reqs)
+        with self.obs.tracer.span(TR.CAT_ADMISSION, "serve.hydrate"):
+            stacked = self._hydrate_stacked(reqs)
         prefix_rows = None
         if stacked is not None and self.prefix_len:
             # prefix KV rows hydrate into the cache at prefill, not into
@@ -1307,14 +1333,16 @@ class ServeEngine:
         slot_of = {id(r): s for r, s in zip(reqs, assigned)}
         if stacked is not None:
             # ONE scatter into the per-slot buffers for the whole wave
-            if self.continuous:
-                entries = jnp.asarray(
-                    [self.mask_alloc.pages_of(r.uid)[0] for r in reqs])
-                self.masks["pool"] = self._scatter_pool(
-                    self.masks["pool"], entries, stacked)
-            else:
-                self.masks = self._scatter_masks(
-                    self.masks, jnp.asarray(assigned), stacked)
+            with self.obs.tracer.span(TR.CAT_ADMISSION,
+                                      "serve.scatter_masks"):
+                if self.continuous:
+                    entries = jnp.asarray(
+                        [self.mask_alloc.pages_of(r.uid)[0] for r in reqs])
+                    self.masks["pool"] = self._scatter_pool(
+                        self.masks["pool"], entries, stacked)
+                else:
+                    self.masks = self._scatter_masks(
+                        self.masks, jnp.asarray(assigned), stacked)
 
         idx_of = {id(r): i for i, r in enumerate(reqs)}
         groups = self.scheduler.group_by_bucket(reqs)
@@ -1340,7 +1368,7 @@ class ServeEngine:
                     cpos = jnp.asarray([r.prefix_len for r in group]
                                        + [0] * (Bp - B), jnp.int32)
                     prows = tuple(t[sel] for t in prefix_rows)
-            with self.obs.tracer.span(TR.CAT_PREFILL, f"prefill[{pad}]",
+            with self.obs.tracer.span(TR.CAT_PREFILL, "serve.prefill",
                                       bucket=pad, rows=Bp, real=B):
                 nxt, mini = self._prefill(self.params, jnp.asarray(toks),
                                           rows, jnp.asarray(lens), cpos,
@@ -1382,17 +1410,18 @@ class ServeEngine:
         # position from it, so prefix-on requests continue at P + prompt
         lens_all = [self._rlen(r) for r in reqs]
         toks_all = [next_toks[id(r)] for r in reqs]
-        self.slots.admit(assigned, toks_all, lens_all,
-                         [r.max_new_tokens for r in reqs])
-        for r, slot in zip(reqs, assigned):
-            r.generated.append(next_toks[id(r)])
-            if r.max_new_tokens <= 1 or self._rlen(r) >= self.S - 1:
-                r.done = True  # budget spent by the prefill token
-                if self.continuous:
-                    self._release_request(slot, r)
-            else:
-                self.slot_req[slot] = r
-                self.slot_degraded[slot] = r.degraded
+        with self.obs.tracer.span(TR.CAT_ADMISSION, "serve.slot_admit"):
+            self.slots.admit(assigned, toks_all, lens_all,
+                             [r.max_new_tokens for r in reqs])
+            for r, slot in zip(reqs, assigned):
+                r.generated.append(next_toks[id(r)])
+                if r.max_new_tokens <= 1 or self._rlen(r) >= self.S - 1:
+                    r.done = True  # budget spent by the prefill token
+                    if self.continuous:
+                        self._release_request(slot, r)
+                else:
+                    self.slot_req[slot] = r
+                    self.slot_degraded[slot] = r.degraded
         self._refresh_window()
         return len(reqs)
 
@@ -1420,7 +1449,20 @@ class ServeEngine:
         continuous mode, their pages/entries — then resume preempted work
         into the freed capacity). Returns the number of still-active
         slots."""
-        s = self.slots.sync()
+        with self.obs.tracer.span(TR.CAT_DECODE_WINDOW, "serve.sync") as sp:
+            with self.obs.tracer.span(TR.CAT_DECODE_WINDOW, "serve.fetch"):
+                s = self.slots.sync()
+            before = self.decode_tokens
+            with self.obs.tracer.span(TR.CAT_DECODE_WINDOW,
+                                      "serve.distribute"):
+                self._distribute(s)
+            sp.update(fill=s.fill, tokens=self.decode_tokens - before)
+            self._refresh_window()
+        return self.active_count()
+
+    def _distribute(self, s) -> None:
+        """The window's tokens to their requests; finished requests done
+        and released, the obs flush, then resumes into freed capacity."""
         if s.fill:
             # capacity accounting: an occupied slot that emitted fewer
             # tokens than the window stepped idled the difference
@@ -1461,8 +1503,6 @@ class ServeEngine:
         self._flush_obs(s)
         if self.continuous and self._resume_q:
             self._try_resume()
-        self._refresh_window()
-        return self.active_count()
 
     def _flush_obs(self, s) -> None:
         """Observability flush at the sync boundary — the ONLY place decode
@@ -1476,10 +1516,6 @@ class ServeEngine:
             m = self.obs.metrics
             m.inc("serve.decode_tokens", toks)
             m.inc("serve.device_steps", s.fill)
-            m.inc("serve.active_slot_steps",
-                  int(acc[:, OBS.OBS_ACTIVE_STEPS].sum()))
-            m.inc("serve.stranded_slot_steps",
-                  int(acc[:, OBS.OBS_STRANDED_STEPS].sum()))
             elapsed = now - self._win_t0
             if toks:
                 # mean host-side per-token latency over this window (the
@@ -1488,7 +1524,8 @@ class ServeEngine:
                           "us")
             m.observe("serve.queue_depth", self.scheduler.pending(), "reqs")
             m.set_gauge("serve.queue_depth_now", self.scheduler.pending())
-            self.obs.tracer.complete(TR.CAT_DECODE_WINDOW, "decode_window",
+            self.obs.tracer.complete(TR.CAT_DECODE_WINDOW,
+                                     "serve.decode_window",
                                      self._win_t0, now, steps=s.fill,
                                      tokens=toks)
             if s.drafted is not None:
@@ -1497,43 +1534,48 @@ class ServeEngine:
                     m.inc("serve.spec_drafted", d)
                     m.inc("serve.spec_accepted", a)
                     m.observe("serve.spec_accept_rate", a / d, "ratio")
-                    self.obs.tracer.instant(TR.CAT_SPEC, "spec_window",
+                    self.obs.tracer.instant(TR.CAT_SPEC, "serve.spec_window",
                                             drafted=d, accepted=a,
                                             rounds=s.fill)
         self.obs.sentinel.check()
         self._win_t0 = now
 
     def _refresh_window(self) -> None:
-        # device capacity stop is lengths >= S-1 post-increment with
-        # lengths = prompt + generated - 1, so a slot can still emit
-        # S - prompt - generated tokens (not S-1 - ...). Windowed mode
-        # bounds the window by the MAX remaining (don't dead-step after
-        # everyone finished); continuous mode by the MIN remaining — greedy
-        # decode retires deterministically, so the sync lands exactly when
-        # the first slot frees and its capacity turns over immediately.
-        remaining = [min(r.max_new_tokens - len(r.generated),
-                         self.S - self._rlen(r) - len(r.generated))
-                     for r in self.slot_req if r is not None]
-        if self.continuous:
-            bound = min(remaining) if remaining else self.sync_every
-        else:
-            bound = max(remaining) if remaining else self.sync_every
-        # spec mode windows count ROUNDS (up to W tokens each): the first
-        # retirement can land after as few as ceil(bound / W) rounds, so
-        # the sync bound shrinks accordingly (an early sync just costs one
-        # host round-trip; a late one would strand the freed slot)
-        W = self.spec_gamma + 1 if self.spec else 1
-        self._window = max(1, min(self.sync_every, -(-bound // W)))
-        if self.continuous:
-            # page growth must cover every position the window can WRITE —
-            # rounds x W tokens (draft + verify spans), not rounds tokens
-            self._ensure_window_pages(self._window * W)
-            self._push_tables()
-            if self.masks is not None and self._view_dirty:
-                self._view_dirty = False
-                self._masks_view = self._gather_mask_view(
-                    self.masks["pool"], self.masks["table"])
-        self._backlog = bool(self.scheduler.pending() or self._resume_q)
+        with self.obs.tracer.span(TR.CAT_DECODE_WINDOW,
+                                  "serve.refresh_window"):
+            # device capacity stop is lengths >= S-1 post-increment with
+            # lengths = prompt + generated - 1, so a slot can still emit
+            # S - prompt - generated tokens (not S-1 - ...). Windowed mode
+            # bounds the window by the MAX remaining (don't dead-step
+            # after everyone finished); continuous mode by the MIN remaining
+            # — greedy decode retires deterministically, so the sync lands
+            # exactly when the first slot frees and its capacity turns over
+            # immediately.
+            remaining = [min(r.max_new_tokens - len(r.generated),
+                             self.S - self._rlen(r) - len(r.generated))
+                         for r in self.slot_req if r is not None]
+            if self.continuous:
+                bound = min(remaining) if remaining else self.sync_every
+            else:
+                bound = max(remaining) if remaining else self.sync_every
+            # spec mode windows count ROUNDS (up to W tokens each): the
+            # first retirement can land after as few as ceil(bound / W)
+            # rounds, so the sync bound shrinks accordingly (an early sync
+            # just costs one host round-trip; a late one would strand the
+            # freed slot)
+            W = self.spec_gamma + 1 if self.spec else 1
+            self._window = max(1, min(self.sync_every, -(-bound // W)))
+            if self.continuous:
+                # page growth must cover every position the window can
+                # WRITE — rounds x W tokens (draft + verify spans), not
+                # rounds tokens
+                self._ensure_window_pages(self._window * W)
+                self._push_tables()
+                if self.masks is not None and self._view_dirty:
+                    self._view_dirty = False
+                    self._masks_view = self._gather_mask_view(
+                        self.masks["pool"], self.masks["table"])
+            self._backlog = bool(self.scheduler.pending() or self._resume_q)
 
     def submit(self, reqs) -> None:
         """Queue requests with the scheduler (admitted as slots free up)."""

@@ -251,6 +251,9 @@ def make_paged_cache(cache_template, n_pages: int, page_size: int,
     return {"data": map_with_path(one, cache_template), "table": table}
 
 
+# the paged decode's gather and scatter run under these scopes, which name
+# their ops in the compiled step's metadata and in device traces
+@jax.named_scope("kv_dense_view")
 def dense_view(data, table, page_size: int):
     """Gather the paged leaves back to the dense ``[lead, B, S, ...]``
     layout through the page table (sentinel entries clamp to a junk page
@@ -270,6 +273,7 @@ def dense_view(data, table, page_size: int):
     return map_with_path(one, data)
 
 
+@jax.named_scope("kv_writeback")
 def writeback(data, dense_new, table, lengths, active, page_size: int):
     """Scatter the ONE decode-written position (``lengths[b]``) of every
     paged leaf back into its page; resident leaves take the model's new
@@ -293,6 +297,7 @@ def writeback(data, dense_new, table, lengths, active, page_size: int):
     return map_with_paths(one, data, dense_new)
 
 
+@jax.named_scope("kv_writeback")
 def writeback_span(data, dense_new, table, lengths, span: int, active,
                    page_size: int):
     """Scatter ``span`` consecutive written positions per slot

@@ -85,6 +85,10 @@ class Scheduler:
         self.n_admitted = 0
         self.n_promoted = 0
         self.n_requeued = 0
+        # queue time of admitted requests: seconds from submit() to the
+        # start of the wave that admitted them, and how many were timed
+        self.queue_wait_s = 0.0
+        self.waited = 0
 
     def __len__(self) -> int:
         return len(self._queue)
@@ -161,6 +165,15 @@ class Scheduler:
         self.n_admitted += len(picked)
         return picked
 
+    def record_wait(self, reqs: List[Request], t_wave: float) -> None:
+        """Count the queue time of requests admitted by a wave that
+        started at host time ``t_wave`` (requests never submit()-ted carry
+        no submit time and are not timed)."""
+        for r in reqs:
+            if r.t_submit:
+                self.queue_wait_s += t_wave - r.t_submit
+                self.waited += 1
+
     def group_by_bucket(self, reqs: List[Request]) -> Dict[int, List[Request]]:
         """Admission-wave requests -> {padded_len: [reqs]} prefill groups."""
         groups: Dict[int, List[Request]] = {}
@@ -175,6 +188,8 @@ class Scheduler:
         self.n_admitted = 0
         self.n_promoted = 0
         self.n_requeued = 0
+        self.queue_wait_s = 0.0
+        self.waited = 0
 
     def stats(self) -> dict:
         return {"pending": len(self._queue),
@@ -182,4 +197,6 @@ class Scheduler:
                 "admitted": self.n_admitted,
                 "policy": self.policy,
                 "promoted": self.n_promoted,
-                "requeued": self.n_requeued}
+                "requeued": self.n_requeued,
+                "queue_wait_s": self.queue_wait_s,
+                "waited": self.waited}

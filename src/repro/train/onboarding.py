@@ -129,47 +129,52 @@ class OnboardingScheduler:
     # ------------------------------------------------------------ lifecycle
     def fill(self, rstate: dict, batcher: RosterBatcher) -> dict:
         """Admit pending profiles into every free slot (one wave)."""
-        admitted = False
-        for slot in range(self.roster.capacity):
-            if self.slot_pid[slot] is None and self.pending:
-                pid = self.pending.popleft()
-                rstate = self.roster.admit(rstate, slot, pid)
-                self.slot_pid[slot] = pid
-                batcher.slot_pids[slot] = pid
-                admitted = True
+        admitted = 0
+        with self.obs.tracer.span(TR.CAT_GRADUATION, "train.fill") as sp:
+            for slot in range(self.roster.capacity):
+                if self.slot_pid[slot] is None and self.pending:
+                    pid = self.pending.popleft()
+                    rstate = self.roster.admit(rstate, slot, pid)
+                    self.slot_pid[slot] = pid
+                    batcher.slot_pids[slot] = pid
+                    admitted += 1
+            sp["admitted"] = admitted
         if admitted:
             self.admission_waves += 1
         return rstate
 
     def poll(self, rstate: dict, batcher: RosterBatcher) -> dict:
         """Sync-cadence pass: ONE device fetch, then graduate/evict/refill."""
-        met = self.roster.metrics(rstate, self.policy.ema_decay)
-        pol = self.policy
-        for slot, pid in enumerate(self.slot_pid):
-            if pid is None:
-                continue
-            # strike check FIRST: a poisoned slot's slot_step freezes (the
-            # finite guard skips its updates), so it would otherwise sit
-            # below min_steps forever, pinning the slot
-            if int(met["nonfinite"][slot]) >= pol.max_poison_strikes:
-                rstate = self.quarantine(rstate, slot, met)
-                batcher.slot_pids[slot] = None
-                continue
-            steps = int(met["slot_step"][slot])
-            if steps < pol.min_steps:
-                continue
-            converged = (
-                (pol.target_loss is not None
-                 and met["ema_loss"][slot] <= pol.target_loss) or
-                (pol.target_acc is not None
-                 and met["ema_acc"][slot] >= pol.target_acc))
-            if converged or steps >= pol.max_steps:
-                if converged or not pol.evict_at_max:
-                    rstate = self.graduate(rstate, slot, met)
-                else:
-                    rstate = self.evict(rstate, slot, met)
-                batcher.slot_pids[slot] = None
-        return self.fill(rstate, batcher)
+        with self.obs.tracer.span(TR.CAT_GRADUATION, "train.poll"):
+            with self.obs.tracer.span(TR.CAT_GRADUATION,
+                                      "train.metrics_fetch"):
+                met = self.roster.metrics(rstate, self.policy.ema_decay)
+            pol = self.policy
+            for slot, pid in enumerate(self.slot_pid):
+                if pid is None:
+                    continue
+                # strike check FIRST: a poisoned slot's slot_step freezes (the
+                # finite guard skips its updates), so it would otherwise sit
+                # below min_steps forever, pinning the slot
+                if int(met["nonfinite"][slot]) >= pol.max_poison_strikes:
+                    rstate = self.quarantine(rstate, slot, met)
+                    batcher.slot_pids[slot] = None
+                    continue
+                steps = int(met["slot_step"][slot])
+                if steps < pol.min_steps:
+                    continue
+                converged = (
+                    (pol.target_loss is not None
+                     and met["ema_loss"][slot] <= pol.target_loss) or
+                    (pol.target_acc is not None
+                     and met["ema_acc"][slot] >= pol.target_acc))
+                if converged or steps >= pol.max_steps:
+                    if converged or not pol.evict_at_max:
+                        rstate = self.graduate(rstate, slot, met)
+                    else:
+                        rstate = self.evict(rstate, slot, met)
+                    batcher.slot_pids[slot] = None
+            return self.fill(rstate, batcher)
 
     def _record(self, slot: int, met: dict) -> dict:
         return {"pid": int(self.slot_pid[slot]), "slot": int(slot),
@@ -183,37 +188,39 @@ class OnboardingScheduler:
         the profile's aggregated Â/B̂, quantized ON WRITE (the store owns
         the scheme) — the train-side half of the quantized serving path."""
         pid = self.slot_pid[slot]
-        prof = self.roster.slot_params(rstate, slot)
-        agg = None
-        if self.store.quant != "none" and not self.xp.is_hetero:
-            # hetero banks graduate masks-only even into quantized stores:
-            # the agg_* record format is the bottleneck (Â, B̂) pair, which
-            # has no single-tensor analogue across mixed families —
-            # admission falls back to the sparse bank-read path.
-            from repro.core import xpeft as XP
-            eff = XP.precompute_effective_adapters(self.bank, prof, self.xp)
-            agg = (eff["a_hat"], eff["b_hat"])
-        self.store.add_profile(pid, prof, agg=agg)
-        rec = self._record(slot, met)
-        self.graduated.append(rec)
-        self.obs.tracer.instant(TR.CAT_GRADUATION, "graduate",
-                                profile=int(pid), slot=int(slot),
-                                steps=rec["steps"])
-        self.obs.metrics.inc("train.graduated")
-        rstate = self.roster.evict(rstate, slot)
-        self.slot_pid[slot] = None
+        with self.obs.tracer.span(TR.CAT_GRADUATION, "train.graduate",
+                                  profile=int(pid), slot=int(slot)) as sp:
+            prof = self.roster.slot_params(rstate, slot)
+            agg = None
+            if self.store.quant != "none" and not self.xp.is_hetero:
+                # hetero banks graduate masks-only even into quantized
+                # stores: the agg_* record format is the bottleneck (Â, B̂)
+                # pair, which has no single-tensor analogue across mixed
+                # families — admission falls back to the sparse bank-read
+                # path.
+                from repro.core import xpeft as XP
+                eff = XP.precompute_effective_adapters(self.bank, prof,
+                                                       self.xp)
+                agg = (eff["a_hat"], eff["b_hat"])
+            self.store.add_profile(pid, prof, agg=agg)
+            rec = self._record(slot, met)
+            sp["steps"] = rec["steps"]
+            self.graduated.append(rec)
+            self.obs.metrics.inc("train.graduated")
+            rstate = self.roster.evict(rstate, slot)
+            self.slot_pid[slot] = None
         return rstate
 
     def evict(self, rstate: dict, slot: int, met: dict) -> dict:
         """Drop an unconverged occupant without graduating it."""
         rec = self._record(slot, met)
-        self.evicted.append(rec)
-        self.obs.tracer.instant(TR.CAT_GRADUATION, "evict",
-                                profile=rec["pid"], slot=int(slot),
-                                steps=rec["steps"])
-        self.obs.metrics.inc("train.evicted")
-        rstate = self.roster.evict(rstate, slot)
-        self.slot_pid[slot] = None
+        with self.obs.tracer.span(TR.CAT_GRADUATION, "train.evict",
+                                  profile=rec["pid"], slot=int(slot),
+                                  steps=rec["steps"]):
+            self.evicted.append(rec)
+            self.obs.metrics.inc("train.evicted")
+            rstate = self.roster.evict(rstate, slot)
+            self.slot_pid[slot] = None
         return rstate
 
     def quarantine(self, rstate: dict, slot: int, met: dict) -> dict:
@@ -224,7 +231,7 @@ class OnboardingScheduler:
         rec = self._record(slot, met)
         rec["nonfinite"] = int(met["nonfinite"][slot])
         self.quarantined.append(rec)
-        self.obs.tracer.instant(TR.CAT_RESILIENCE, "quarantine",
+        self.obs.tracer.instant(TR.CAT_RESILIENCE, "train.quarantine",
                                 profile=rec["pid"], slot=int(slot),
                                 nonfinite=rec["nonfinite"])
         self.obs.metrics.inc("train.quarantined")

@@ -133,7 +133,9 @@ class Trainer:
             return []
         steps, mets = zip(*self._pending)
         self._pending = []
-        host = jax.device_get(list(mets))
+        with self.obs.tracer.span(TR.CAT_GANG_STEP, "train.flush",
+                                  steps=len(steps)):
+            host = jax.device_get(list(mets))
         self.host_syncs += 1
         slow = False
         if self._window_t0 is not None:
@@ -143,7 +145,7 @@ class Trainer:
             # one span per flushed WINDOW (per-step device time is not
             # observable without a per-step block — same reasoning as the
             # watchdog scoring above); sentinel check rides the boundary
-            self.obs.tracer.complete(TR.CAT_GANG_STEP, "gang_window",
+            self.obs.tracer.complete(TR.CAT_GANG_STEP, "train.gang_window",
                                      self._window_t0, now,
                                      steps=len(steps), straggler=slow)
             self.obs.metrics.inc("train.steps", len(steps))
