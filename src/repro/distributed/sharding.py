@@ -249,14 +249,27 @@ def cache_specs(abstract_cache, mesh: Mesh, cfg, batch: int):
 
 def paged_cache_specs(paged_cache, mesh: Mesh, cfg, n_slots: int):
     """Sharding for the continuous engine's block-paged cache
-    (`serve/pages.py`): pool leaves are ``[lead, n_pages, page, ...]`` —
-    the PAGE axis sits where the dense cache's slot axis sat, so
-    `cache_specs` applies verbatim (pages over "data", kv heads dim 3 over
-    "model", recurrent resident leaves unchanged) and the PR-4 invariant
-    "pages sharded like the slot axis" holds by construction. The page
-    table shards its slot axis over "data" like every slot-packed array."""
+    (`serve/pages.py`): pool leaves are lane-dense
+    ``[lead, n_pages, page, KV*hd]`` — the PAGE axis sits where the dense
+    cache's slot axis sat, so `cache_specs` of the unfolded
+    ``[lead, n_pages, page, KV, hd]`` applies (pages over "data", kv heads
+    over "model" — whole heads of the lane axis —, recurrent resident
+    leaves unchanged) and the PR-4 invariant "pages sharded like the slot
+    axis" holds by construction. The page table shards its slot axis over
+    "data" like every slot-packed array."""
     dsize = dict(mesh.shape).get("data", 1)
-    data = cache_specs(paged_cache["data"], mesh, cfg, n_slots)
+    KV = cfg.num_kv_heads
+
+    def one(path, x):
+        name = path.rsplit("/", 1)[-1]
+        if name in ("k", "v", "attn_k", "attn_v") and len(x.shape) == 4:
+            unfolded = jax.ShapeDtypeStruct(
+                tuple(x.shape[:3]) + (KV, x.shape[3] // KV), x.dtype)
+            spec = cache_specs({name: unfolded}, mesh, cfg, n_slots)[name]
+            return P(*spec[:4])             # hd is never sharded
+        return cache_specs({name: x}, mesh, cfg, n_slots)[name]
+
+    data = map_with_path(one, paged_cache["data"])
     t = paged_cache["table"].shape
     lead = "data" if t[0] % dsize == 0 and t[0] >= dsize else None
     return {"data": data, "table": P(lead, None)}

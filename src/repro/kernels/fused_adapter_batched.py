@@ -23,8 +23,10 @@ block 0 and no [B, d, b] materialization happens.
 
 VMEM budget at decode defaults (block_t<=256, d=8192, b=128, bf16):
 x tile 4 MiB + Â 2 MiB + B̂ 2 MiB + out 4 MiB ≈ 12 MiB < 16 MiB v5e VMEM.
-As with the unbatched kernel, pad b to the 128 lane width on real TPUs
-(LN then masks the padded columns — see ops.py).
+Both projections are read as lane-dense ``[b, d]`` rows (Â transposed):
+the TPU stores a ``[.., d, b]`` array with b below 128 lanes that way by
+default, so a decode step slices each layer's Â out of the slot records
+with no relayout copy, and no row is padded to 128 lanes.
 """
 from __future__ import annotations
 
@@ -38,7 +40,8 @@ from jax.experimental import pallas as pl
 def _kernel(x_ref, a_ref, b_ref, ls_ref, lb_ref, o_ref, *, activation, eps,
             use_ln):
     x = x_ref[0]                                            # [block_t, d]
-    h = jnp.dot(x, a_ref[0], preferred_element_type=jnp.float32)
+    h = jax.lax.dot_general(x, a_ref[0], (((1,), (1,)), ((), ())),
+                            preferred_element_type=jnp.float32)  # x Â
     if use_ln:
         mu = jnp.mean(h, axis=-1, keepdims=True)
         var = jnp.mean(jnp.square(h - mu), axis=-1, keepdims=True)
@@ -77,6 +80,10 @@ def fused_adapter_batched(x, a_hat, b_hat, ln_scale, ln_bias, *,
     shared_ln = ln_scale.ndim == 1
     if shared_proj:
         a_hat, b_hat = a_hat[None], b_hat[None]
+    # the kernel reads Â transposed, [b, d] rows: with b below the 128
+    # lanes the TPU's default layout of a [.., d, b] array already stores
+    # it so, and this transpose is then a bitcast instead of a relayout
+    a_t = jnp.swapaxes(a_hat, 1, 2)
     # ln rows as [B, 1, b]: a (1, b) block of a [B, b] array breaks the
     # TPU's (8, 128) tiling rule; a unit second-minor axis does not
     ln_scale = ln_scale.reshape(-1, 1, b)
@@ -93,7 +100,7 @@ def fused_adapter_batched(x, a_hat, b_hat, ln_scale, ln_bias, *,
         grid=(B, T // block_t),
         in_specs=[
             pl.BlockSpec((1, block_t, d), lambda bi, ti: (bi, ti, 0)),
-            pl.BlockSpec((1, d, b), row_p),
+            pl.BlockSpec((1, b, d), row_p),
             pl.BlockSpec((1, b, d), row_p),
             pl.BlockSpec((1, 1, b), row_l),
             pl.BlockSpec((1, 1, b), row_l),
@@ -101,4 +108,4 @@ def fused_adapter_batched(x, a_hat, b_hat, ln_scale, ln_bias, *,
         out_specs=pl.BlockSpec((1, block_t, d), lambda bi, ti: (bi, ti, 0)),
         out_shape=jax.ShapeDtypeStruct((B, T, d), x.dtype),
         interpret=interpret,
-    )(x, a_hat, b_hat, ln_scale, ln_bias)
+    )(x, a_t, b_hat, ln_scale, ln_bias)

@@ -58,6 +58,8 @@ from repro.kernels.mask_aggregate import (
     mask_aggregate_batched as _agg_pallas_batched)
 from repro.kernels.mask_aggregate_quant import (
     mask_aggregate_quant_batched as _agg_pallas_quant)
+from repro.kernels.paged_decode_attention import (
+    paged_decode_attention as _paged_pallas)
 from repro.quant.schemes import check_scheme
 
 IMPLS = ("auto", "pallas", "interpret", "ref")
@@ -230,6 +232,36 @@ def decode_block_fused(x, pos, block, k_cache, v_cache, masks_l, *,
                                     masks_l, **kw)
     return decode_block_pallas(x, pos, block, k_cache, v_cache, masks_l,
                                interpret=impl == "interpret", **kw)
+
+
+def paged_decode_supported(impl: str, page: int, row_elems: int,
+                           dtype) -> bool:
+    """Whether ``paged_decode_attention`` can serve a pool of ``page``-row
+    pages of ``row_elems`` lanes here. Never under a kernel mesh: the
+    pool's pages shard over "data" while a slot's pages may sit on any
+    shard, so those steps keep the dense view. Compiled, a page must be
+    whole packed tiles of a lane-dense row."""
+    if _MESH.get() is not None:
+        return False
+    if resolve_impl(impl) != "pallas":
+        return True
+    rows_per_tile = 32 // jnp.dtype(dtype).itemsize
+    return row_elems % 128 == 0 and page % rows_per_tile == 0
+
+
+def paged_decode_attention(q, k_new, v_new, k_pool, v_pool, layer, table,
+                           lengths, *, impl: str = "auto"):
+    """T=1 decode attention straight from the stacked page pool: q [B,H,hd]
+    and the new token's k/v rows [B,KV,hd] against pools
+    [L, n_pages, page, KV*hd] at ``layer`` through ``table`` [B, mp];
+    slot b attends its ``lengths[b]`` cached positions plus the new row.
+    -> [B, H, hd]."""
+    impl = resolve_impl(impl)
+    if impl == "ref":
+        return ref.paged_decode_attention_ref(q, k_new, v_new, k_pool,
+                                              v_pool, layer, table, lengths)
+    return _paged_pallas(q, k_new, v_new, k_pool, v_pool, layer, table,
+                         lengths, interpret=impl == "interpret")
 
 
 # ----------------------------------------------------------------------------

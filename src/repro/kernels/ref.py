@@ -154,3 +154,35 @@ def fused_adapter_batched_ref(x, a_hat, b_hat, ln_scale, ln_bias, *,
     else:
         y = jnp.einsum("btc,bcd->btd", h, b32)
     return (x32 + y).astype(x.dtype)
+
+
+def paged_decode_attention_ref(q, k_new, v_new, k_pool, v_pool, layer,
+                               table, lengths):
+    """jnp twin of kernels/paged_decode_attention.py: slot b's query
+    attends, in float32, the cached positions below ``lengths[b]`` that
+    sit on real pages of ``layer`` (sentinel entries are never read),
+    then its own new ``k``/``v`` row. q [B,H,hd], rows [B,KV,hd], pools
+    [L, n_pages, page, KV*hd], table [B, mp] -> [B, H, hd]."""
+    B, H, hd = q.shape
+    KV = k_new.shape[1]
+    n_pages, page = k_pool.shape[1:3]
+
+    def layer_pages(pool):                                  # [B, S, KV, hd]
+        pages = jnp.take(pool[layer], table, axis=0, mode="clip")
+        return pages.reshape(B, -1, KV, hd).astype(jnp.float32)
+
+    keys = jnp.concatenate([layer_pages(k_pool),
+                            k_new[:, None].astype(jnp.float32)], axis=1)
+    vals = jnp.concatenate([layer_pages(v_pool),
+                            v_new[:, None].astype(jnp.float32)], axis=1)
+    S = keys.shape[1] - 1
+    pos = jnp.arange(S)
+    live = (pos[None] < lengths[:, None]) & jnp.repeat(table < n_pages,
+                                                       page, axis=1)
+    live = jnp.concatenate([live, jnp.ones((B, 1), bool)], axis=1)
+    qg = q.astype(jnp.float32).reshape(B, KV, H // KV, hd)
+    s = jnp.einsum("bkgh,bskh->bkgs", qg, keys) / jnp.sqrt(jnp.float32(hd))
+    s = jnp.where(live[:, None, None], s, -jnp.inf)
+    p = jax.nn.softmax(s, axis=-1)
+    out = jnp.einsum("bkgs,bskh->bkgh", p, vals)
+    return out.reshape(B, H, hd).astype(q.dtype)

@@ -7,15 +7,28 @@ masked (rectangle); the §Perf log treats removing that waste as a hillclimb.
 """
 from __future__ import annotations
 
-from typing import Optional
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
 
 from repro.distributed import ctx
+from repro.kernels import ops
 from repro.models.common import apply_rope, dense_init, softcap
 
 NEG_INF = -2.0e38
+
+
+class PagedKV(NamedTuple):
+    """Read-only view of the continuous engine's stacked page pools for
+    T=1 decode: each layer attends its slots' pages in place
+    (``ops.paged_decode_attention``; under the ``ref`` impl, a gather of
+    the layer's pages and the dense cached path) and returns its new K/V
+    rows instead of an updated cache."""
+    k: jax.Array          # [L, n_pages, page, KV*hd]
+    v: jax.Array
+    table: jax.Array      # [B, max_pages] int32, n_pages = sentinel
+    lengths: jax.Array    # [B] cached positions each slot attends
 
 
 def init_attention(key, cfg, dtype) -> dict:
@@ -145,11 +158,15 @@ def _cache_update(cache, k, v, cache_pos):
 
 def attention(params, x, *, positions, cfg, cache=None, cache_pos=None,
               is_global=True, q_chunk=512, k_chunk=1024, extra_kv=None,
-              front_skip=None):
+              front_skip=None, paged=None):
     """x [B,T,d] -> (y [B,T,d], new_cache).
 
     cache: {"k","v": [B, S, KV, hd]} functional KV cache; cache_pos: scalar
     write offset. Without a cache, keys=queries (self-attention).
+
+    paged: ``(PagedKV, layer)`` — T=1 decode straight from the page pools
+    (full causal attention only); new_cache is then this token's rows
+    ``{"k","v": [B, KV, hd]}``, written to the pools after the layer scan.
 
     front_skip: optional [B] int32 — mask the first ``front_skip[b]`` KEY
     buffer slots in the cached path (serving over hydrated prefix KV rows:
@@ -192,6 +209,26 @@ def attention(params, x, *, positions, cfg, cache=None, cache_pos=None,
         # non-divisible KV: q-seq CP if the launcher enabled the "q_seq"
         # rule (no-op otherwise; K/V-seq CP carries the TP by default)
         q = ctx.hint(q, "batch", "q_seq", None, None)
+
+    rows = None
+    if paged is not None:
+        pkv, layer = paged
+        rows = {"k": k[:, 0], "v": v[:, 0]}
+        if ops.resolve_impl(cfg.xpeft.kernel_impl) != "ref":
+            with jax.named_scope("kv_dense_view"):
+                out = ops.paged_decode_attention(
+                    q[:, 0], k[:, 0], v[:, 0], pkv.k, pkv.v, layer,
+                    pkv.table, pkv.lengths, impl=cfg.xpeft.kernel_impl)
+            y = jnp.einsum("bthk,hkd->btd", out[:, None], params["wo"])
+            return y, rows
+        # the jnp route: gather this layer's pages to the dense layout and
+        # attend through the cached path below, so the tokens stay bitwise
+        # those of the dense cache (junk pages sit past kv_valid)
+        with jax.named_scope("kv_dense_view"):
+            cache = {n: jnp.take(pool[layer], pkv.table, axis=0, mode="clip")
+                     .reshape(B, -1, KV, hd)
+                     for n, pool in (("k", pkv.k), ("v", pkv.v))}
+        cache_pos = pkv.lengths
 
     k_idx = None
     if cache is not None:
@@ -254,4 +291,4 @@ def attention(params, x, *, positions, cfg, cache=None, cache_pos=None,
 
     out = out.transpose(0, 3, 1, 2, 4).reshape(B, T, H, hd)
     y = jnp.einsum("bthk,hkd->btd", out, params["wo"])
-    return y, new_cache
+    return y, (new_cache if rows is None else rows)

@@ -255,12 +255,12 @@ def _decode_fused_apply(block, x, masks_l, cfg, *, positions, cache_l,
 
 
 def _attn_block_apply(block, x, cfg, *, positions, cache_l, cache_pos,
-                      is_global, extra_kv=None, front_skip=None):
+                      is_global, extra_kv=None, front_skip=None, paged=None):
     h = norm_apply(x, block["n1"], cfg.norm)
     h, new_cache = ATT.attention(block["attn"], h, positions=positions,
                                  cfg=cfg, cache=cache_l, cache_pos=cache_pos,
                                  is_global=is_global, extra_kv=extra_kv,
-                                 front_skip=front_skip)
+                                 front_skip=front_skip, paged=paged)
     x = x + h
     h = norm_apply(x, block["n2"], cfg.norm)
     if cfg.moe:
@@ -271,12 +271,26 @@ def _attn_block_apply(block, x, cfg, *, positions, cache_l, cache_pos,
     return x, new_cache, aux
 
 
-def _make_body(cfg, positions, cache_pos, use_cache, fused_route=None):
-    """Scan body over stacked layers for uniform-block archs."""
+def _make_body(cfg, positions, cache_pos, use_cache, fused_route=None,
+               paged=None, paged_masks=None):
+    """Scan body over stacked layers for uniform-block archs. With
+    ``paged`` (an ``ATT.PagedKV``) the scanned cache leaf is the layer
+    index: attention reads the layer's KV pages in place and emits its new
+    K/V rows, and the layer's slot records are read straight from their
+    ``[B, L, ...]`` view ``paged_masks`` (no per-step layer-major copy of
+    them)."""
 
     def body(x, xs):
         block, bank_l, masks_l, is_global, cache_l = xs
-        if not use_cache:
+        layer_paged = None
+        if paged is not None:
+            layer_paged, cache_l = (paged, cache_l), None
+            if paged_masks is not None:
+                masks_l = jax.tree.map(
+                    lambda a: jax.lax.dynamic_index_in_dim(
+                        a, layer_paged[1], axis=1, keepdims=False),
+                    paged_masks)
+        elif not use_cache:
             cache_l = None
         if fused_route is not None:
             # decode megakernel: attention/MLP AND the adapter in one
@@ -320,7 +334,7 @@ def _make_body(cfg, positions, cache_pos, use_cache, fused_route=None):
             x, new_cache, aux = _attn_block_apply(
                 block, x, cfg, positions=positions, cache_l=cache_l,
                 cache_pos=cache_pos, is_global=is_global, extra_kv=extra_kv,
-                front_skip=front_skip)
+                front_skip=front_skip, paged=layer_paged)
         with jax.named_scope("adapter"):
             x = _xpeft_apply(x, bank_l, masks_l, cfg)
         # re-pin the residual stream each layer (Megatron-SP: under
@@ -343,6 +357,28 @@ def _remat(fn, cfg):
     return jax.checkpoint(fn)
 
 
+def paged_decode_route(cfg, profile_masks, T: int, data) -> bool:
+    """Whether a decode step over the paged cache's leaves ``data`` can
+    attend the pages in place (``forward`` with an ``ATT.PagedKV``): T=1
+    full causal attention with no softcap, no decode megakernel, no
+    hydrated prefix rows to gate (``prefix_skip``), a K pool
+    ``[L, n_pages, page, KV*hd]`` held at the compute dtype, and a kernel
+    that can serve that pool here. Everything else decodes through the
+    dense view of the pages."""
+    if not (T == 1 and cfg.block_pattern == "attn"
+            and cfg.attn_type == "full" and cfg.causal
+            and not cfg.logit_softcap and not cfg.decode_fused
+            and not (profile_masks is not None
+                     and "prefix_skip" in profile_masks)):
+        return False
+    from repro.kernels import ops
+    pool = data["k"]
+    return (jnp.dtype(pool.dtype) == jnp.dtype(cfg.dtype)
+            and ops.paged_decode_supported(cfg.xpeft.kernel_impl,
+                                           pool.shape[2], pool.shape[3],
+                                           pool.dtype))
+
+
 def forward(params, tokens, cfg, *, prefix_embeds=None, profile_masks=None,
             cache=None, cache_pos=0, positions=None):
     """tokens [B,T] -> (hidden [B,T',d], new_cache, aux_loss).
@@ -350,6 +386,9 @@ def forward(params, tokens, cfg, *, prefix_embeds=None, profile_masks=None,
     profile_masks: {"w_a","w_b": [B,L,N], "ln_scale","ln_bias": [B,L,b]}
     (per-example hydrated mask weights), or None.
     cache: stacked cache pytree from init_cache; cache_pos: write offset.
+    An ``ATT.PagedKV`` cache (where ``paged_decode_route`` holds) is read
+    in place, and new_cache is then the step's K/V rows
+    ``{"k","v": [L, B, KV, hd]}`` for the caller to write to the pools.
     """
     B, T = tokens.shape
     x = jnp.take(params["embed"], tokens, axis=0)
@@ -395,11 +434,25 @@ def forward(params, tokens, cfg, *, prefix_embeds=None, profile_masks=None,
     bank = params.get("xpeft_bank")
     if bank is None:
         bank = jnp.zeros((cfg.num_layers,), jnp.float32)  # dummy scanned leaf
+    meta = jnp.asarray(layer_meta(cfg))
+
+    if isinstance(cache, ATT.PagedKV):
+        # the caller checked paged_decode_route; the slot records stay in
+        # their [B, L, ...] layout and each layer reads its own slice
+        assert Tt == 1, "paged decode attends one new token per slot"
+        body = _remat(_make_body(cfg, positions, cache_pos, True,
+                                 paged=cache, paged_masks=profile_masks),
+                      cfg)
+        xs = (params["blocks"], bank, None, meta,
+              jnp.arange(cfg.num_layers, dtype=jnp.int32))
+        x, (rows, auxs) = jax.lax.scan(body, x, xs)
+        x = norm_apply(x, params["final_norm"], cfg.norm)
+        return x, rows, jnp.mean(auxs)
+
     masks = None
     if profile_masks is not None:
         # [B, L, ...] -> [L, B, ...] for scan
         masks = jax.tree.map(lambda a: jnp.moveaxis(a, 1, 0), profile_masks)
-    meta = jnp.asarray(layer_meta(cfg))
 
     if cfg.block_pattern == "zamba":
         return _forward_zamba(params, x, cfg, positions, cache, cache_pos,

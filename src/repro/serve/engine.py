@@ -56,6 +56,7 @@ from repro.obs import trace as TR
 from repro.core import xpeft as XP
 from repro.core.profiles import ProfileStore
 from repro.kernels import ops
+from repro.models import attention as ATT
 from repro.models import model as MDL
 from repro.resilience import (InjectedHydrationError, RecordIntegrityError,
                               RetryPolicy, retry_with_backoff)
@@ -426,6 +427,11 @@ class ServeEngine:
                 zv = jax.device_put(zv, self._shardings["masks_view"])
             self._zero_view = zv
 
+        # the route the compiled decode step took (set as it traces):
+        # "paged" reads KV pages in place, "dense_view" gathers them to the
+        # dense layout, "dense" is the windowed engine's dense cache
+        self.decode_route: Optional[str] = None
+        row = (cfg.num_kv_heads, cfg.head_dim)   # a paged row, unfolded
         if continuous and self.spec:
             # speculation round (still ONE jitted program): gamma bare-PLM
             # draft steps (scan over the same paged T=1 decode), then ONE
@@ -436,6 +442,7 @@ class ServeEngine:
             # past the accepted prefix hold stale KV that the causal mask
             # hides and the next round overwrites.
             gamma, W = self.spec_gamma, self.spec_gamma + 1
+            self.decode_route = "dense_view"
 
             def decode_fn(params, cache, last_tok, lengths, masks, active):
                 adapted = None if masks is None else masks["adapted"]
@@ -444,7 +451,7 @@ class ServeEngine:
 
                 def draft_step(carry, _):
                     data, tok, pos = carry
-                    dense = PG.dense_view(data, table, page_size)
+                    dense = PG.dense_view(data, table, page_size, row)
                     hidden, dense, _ = MDL.forward(
                         params, tok[:, None], cfg, profile_masks=zero,
                         cache=dense, cache_pos=pos)
@@ -453,8 +460,8 @@ class ServeEngine:
                     # (the tokens still draft — only their KV is dropped,
                     # and positions that far are never committed anyway)
                     ok = active & (pos < self.S)
-                    data = PG.writeback(data, dense, table, pos, ok,
-                                        page_size)
+                    data = PG.writeback(data, PG.rows_at(dense, pos), table,
+                                        pos, ok, page_size)
                     nxt = greedy_next(MDL.lm_logits(params, hidden, cfg))
                     return (data, nxt, pos + 1), nxt
 
@@ -463,7 +470,7 @@ class ServeEngine:
                     length=gamma)
                 drafts = jnp.moveaxis(drafts, 0, 1)          # [n, gamma]
                 seq = jnp.concatenate([last_tok[:, None], drafts], axis=1)
-                dense = PG.dense_view(data, table, page_size)
+                dense = PG.dense_view(data, table, page_size, row)
                 hidden, dense, _ = MDL.forward(
                     params, seq, cfg, profile_masks=adapted, cache=dense,
                     cache_pos=lengths)
@@ -476,26 +483,42 @@ class ServeEngine:
                 n_acc = jnp.sum(jnp.cumprod(match, axis=1), axis=1)
                 return toks, n_acc, {"data": data, "table": table}
         elif continuous:
-            # paged decode: gather KV through the page table back to the
-            # dense layout forward() already takes (bitwise-identical
-            # values — junk pages only cover positions attention masks to
-            # NEG_INF), then scatter the one written position back to its
-            # page. All inside the ONE jitted slot step. Masks arrive as
-            # the slot-indexed VIEW materialized at table-change time
-            # (entry tables only move at host syncs, so gathering the
-            # record pool per step would be pure overhead).
+            # paged decode, all inside the ONE jitted slot step. Where the
+            # step can read pages in place (MDL.paged_decode_route), each
+            # layer attends its slots' pages through the page table and
+            # emits its new K/V rows, and one scatter after the layer scan
+            # writes them to the (donated) pools. Otherwise KV is gathered
+            # through the page table back to the dense layout forward()
+            # takes (bitwise-identical values — junk pages only cover
+            # positions attention masks to NEG_INF), and the one written
+            # position is scattered back to its page. Masks arrive as the
+            # slot-indexed VIEW materialized at table-change time (entry
+            # tables only move at host syncs, so gathering the record pool
+            # per step would be pure overhead).
             def decode_fn(params, cache, last_tok, lengths, masks, active):
-                dense = PG.dense_view(cache["data"], cache["table"],
-                                      page_size)
-                hidden, dense, _ = MDL.forward(params, last_tok[:, None],
-                                               cfg, profile_masks=masks,
-                                               cache=dense,
-                                               cache_pos=lengths)
-                data = PG.writeback(cache["data"], dense, cache["table"],
-                                    lengths, active, page_size)
+                data, table = cache["data"], cache["table"]
+                if self._paged and MDL.paged_decode_route(cfg, masks, 1,
+                                                          data):
+                    self.decode_route = "paged"
+                    kv = ATT.PagedKV(data["k"], data["v"], table,
+                                     jnp.where(active, lengths, 0))
+                    hidden, rows, _ = MDL.forward(
+                        params, last_tok[:, None], cfg, profile_masks=masks,
+                        cache=kv, cache_pos=lengths)
+                else:
+                    self.decode_route = "dense_view"
+                    dense = PG.dense_view(data, table, page_size, row)
+                    hidden, dense, _ = MDL.forward(
+                        params, last_tok[:, None], cfg, profile_masks=masks,
+                        cache=dense, cache_pos=lengths)
+                    rows = PG.rows_at(dense, lengths)
+                data = PG.writeback(data, rows, table, lengths, active,
+                                    page_size)
                 return greedy_next(MDL.lm_logits(params, hidden, cfg)), \
-                    {"data": data, "table": cache["table"]}
+                    {"data": data, "table": table}
         else:
+            self.decode_route = "dense"
+
             def decode_fn(params, cache, last_tok, lengths, masks, active):
                 hidden, cache, _ = MDL.forward(params, last_tok[:, None],
                                                cfg, profile_masks=masks,
@@ -1706,6 +1729,12 @@ class ServeEngine:
                 self.useful_slot_steps,
                 self.n_slots * self.slots.device_steps),
             "step_traces": self.slots.step_traces,
+            # which route the compiled decode step took ("paged" /
+            # "dense_view" / "dense"), and the decode steps run by route:
+            # the step compiles once, so all of them took that route
+            "decode_route": self.decode_route,
+            "steps_by_route": ({self.decode_route: self.slots.device_steps}
+                               if self.decode_route else {}),
             "resident_bytes_per_device": self.resident_bytes_per_device(),
             "host_syncs": self.slots.host_syncs,
             "device_steps": self.slots.device_steps,

@@ -17,13 +17,22 @@ Two layers live here:
   pages to data-mesh shards so a slot's pages stay on its shard), owner
   tracking that makes double-booking structurally impossible, OOM raised
   BEFORE any state mutates, and ``compact()`` for pool-shrink remaps.
-- pure jit-friendly DEVICE helpers — ``dense_view`` (page-table gather
-  back to the dense ``[lead, B, S, ...]`` layout the model's attention
-  already understands, so paged decode is BITWISE identical to the dense
-  cache), ``writeback`` (scatter the one decode-written position back to
-  its page, dropped for slots whose pages may since be re-owned),
+- pure jit-friendly DEVICE helpers — ``writeback`` (scatter each slot's
+  one new K/V row into its page, dropped for slots whose pages may since
+  be re-owned; ``rows_at`` takes those rows out of a dense-view step's
+  cache), ``dense_view`` (page-table gather back to the dense
+  ``[lead, B, S, ...]`` layout the model's cached attention understands,
+  for the steps that cannot read pages in place — speculation, sliding
+  windows, hydrated prefix rows, f8 caches, the decode megakernel; the
+  in-place T=1 decode reads pages through the table in its attention
+  kernel, ``ops.paged_decode_attention``), ``writeback_span``,
   ``insert_group`` (batched prefill insert), and the per-slot
   extract/restore pair used by preempt/resume swaps.
+
+Paged leaves are stored LANE-DENSE: ``[lead, n_pages, page, KV*hd]``, so
+one layer's page is one contiguous DMA and a 64-wide head dim never sits
+alone in the minor (lane) dimension. A dense ``[.., KV, hd]`` row and a
+pool row differ by a reshape only.
 
 The sentinel page index is ``n_pages`` (one past the pool): gathers clamp
 it to a junk page that attention masks out (positions >= kv_valid), and
@@ -233,17 +242,19 @@ def paged_seq_len(cache_template) -> int:
 def make_paged_cache(cache_template, n_pages: int, page_size: int,
                      n_slots: int) -> dict:
     """Build the paged cache from a dense-cache template (arrays or
-    ShapeDtypeStructs): paged leaves ``[lead, B, S, ...]`` become
-    ``[lead, n_pages, page, ...]`` pools, resident leaves keep their dense
-    shapes with B = n_slots, plus the sentinel-filled page table. Returns
-    ``{"data": tree, "table": [n_slots, S/page] int32}``."""
+    ShapeDtypeStructs): paged leaves ``[lead, B, S, KV, hd]`` become
+    lane-dense ``[lead, n_pages, page, KV*hd]`` pools, resident leaves
+    keep their dense shapes with B = n_slots, plus the sentinel-filled
+    page table. Returns ``{"data": tree, "table": [n_slots, S/page]
+    int32}``."""
     S = paged_seq_len(cache_template)
     assert S % page_size == 0, (S, page_size)
 
     def one(path, leaf):
         if leaf_is_paged(path):
-            return jnp.zeros((leaf.shape[0], n_pages, page_size)
-                             + tuple(leaf.shape[3:]), leaf.dtype)
+            row = int(np.prod(leaf.shape[3:]))
+            return jnp.zeros((leaf.shape[0], n_pages, page_size, row),
+                             leaf.dtype)
         return jnp.zeros(leaf.shape, leaf.dtype)
 
     mp = max(S // page_size, 1)
@@ -254,10 +265,11 @@ def make_paged_cache(cache_template, n_pages: int, page_size: int,
 # the paged decode's gather and scatter run under these scopes, which name
 # their ops in the compiled step's metadata and in device traces
 @jax.named_scope("kv_dense_view")
-def dense_view(data, table, page_size: int):
-    """Gather the paged leaves back to the dense ``[lead, B, S, ...]``
+def dense_view(data, table, page_size: int, row_shape):
+    """Gather the paged leaves back to the dense ``[lead, B, S, *row_shape]``
     layout through the page table (sentinel entries clamp to a junk page
-    that attention masks out — every junk position is >= kv_valid).
+    that attention masks out — every junk position is >= kv_valid);
+    ``row_shape`` (the model's ``(KV, hd)``) unfolds the lane-dense row.
     Resident leaves pass through, so the result is exactly the cache tree
     ``models.forward`` already takes: paged decode stays ONE compiled
     program with bitwise-dense numerics."""
@@ -268,33 +280,55 @@ def dense_view(data, table, page_size: int):
             return leaf
         v = jnp.take(leaf, table, axis=1, mode="clip")
         return v.reshape((leaf.shape[0], B, mp * page_size)
-                         + tuple(leaf.shape[3:]))
+                         + tuple(row_shape))
 
     return map_with_path(one, data)
 
 
 @jax.named_scope("kv_writeback")
-def writeback(data, dense_new, table, lengths, active, page_size: int):
-    """Scatter the ONE decode-written position (``lengths[b]``) of every
-    paged leaf back into its page; resident leaves take the model's new
-    value wholesale. Inactive slots route to the sentinel index and are
-    DROPPED — their pad-compute write must never land in a page that may
-    since belong to another slot (a retired slot's table row is already
-    sentinel, so this is belt and braces)."""
+def rows_at(dense_new, lengths):
+    """The one position ``lengths[b]`` of every paged leaf of a dense-view
+    step's new cache ``[lead, B, S, ...]`` -> ``[lead, B, ...]``, the rows
+    ``writeback`` takes; resident leaves pass through."""
+    def one(path, leaf):
+        if not leaf_is_paged(path):
+            return leaf
+        idx = lengths.reshape((1, -1) + (1,) * (leaf.ndim - 2))
+        return jnp.squeeze(jnp.take_along_axis(leaf, idx, axis=2), axis=2)
+
+    return map_with_path(one, dense_new)
+
+
+@jax.named_scope("kv_writeback")
+def writeback(data, rows, table, lengths, active, page_size: int):
+    """Write each slot's ONE new position (``lengths[b]``) of every paged
+    leaf into its page: ``rows`` holds, per paged leaf, the step's new
+    rows ``[lead, B, ...]`` (``rows_at`` takes them out of a dense-view
+    step's cache); resident leaves take the model's new value wholesale.
+    Inactive slots route to the sentinel and are DROPPED — their
+    pad-compute write must never land in a page that may since belong to
+    another slot (a retired slot's table row is already sentinel, so this
+    is belt and braces)."""
     B = table.shape[0]
-    pidx_owned = table[jnp.arange(B), lengths // page_size]
+    pidx = table[jnp.arange(B), lengths // page_size]
     off = lengths % page_size
 
-    def one(path, pool, new):
+    def one(path, pool, leaf):
         if not leaf_is_paged(path):
-            return new
-        idx = lengths.reshape((1, B) + (1,) * (new.ndim - 2))
-        row = jnp.take_along_axis(new, idx, axis=2)
-        row = jnp.squeeze(row, axis=2).astype(pool.dtype)
-        pidx = jnp.where(active, pidx_owned, jnp.int32(pool.shape[1]))
-        return pool.at[:, pidx, off].set(row, mode="drop")
+            return leaf
+        lead, n_pages = pool.shape[:2]
+        # scatter into the pool's [lead * n_pages * page, row] view, one
+        # index per (layer, slot): the indexed axis leads, so the write
+        # needs no relayout of the pool around it
+        flat = (jnp.arange(lead)[:, None] * n_pages + pidx) * page_size + off
+        keep = active & (pidx < n_pages)
+        flat = jnp.where(keep, flat, lead * n_pages * page_size)
+        view = pool.reshape((lead * n_pages * page_size,) + pool.shape[3:])
+        row = leaf.reshape((lead * B,) + pool.shape[3:]).astype(pool.dtype)
+        return view.at[flat.reshape(-1)].set(row, mode="drop").reshape(
+            pool.shape)
 
-    return map_with_paths(one, data, dense_new)
+    return map_with_paths(one, data, rows)
 
 
 @jax.named_scope("kv_writeback")
@@ -321,7 +355,9 @@ def writeback_span(data, dense_new, table, lengths, span: int, active,
             return new
         idx = pos.reshape((1, B, span) + (1,) * (new.ndim - 3))
         rows = jnp.take_along_axis(new, jnp.clip(idx, 0, new.shape[2] - 1),
-                                   axis=2).astype(pool.dtype)
+                                   axis=2)
+        rows = rows.reshape(rows.shape[:3] + pool.shape[3:]).astype(
+            pool.dtype)
         pidx = jnp.where(in_range, pidx_owned, jnp.int32(pool.shape[1]))
         return pool.at[:, pidx, off].set(rows, mode="drop")
 

@@ -226,16 +226,17 @@ class SlotState:
             self.admit_shapes.add(int(slots.shape[0]))
             return _admit_scatter(arrays, slots, *rest)
 
+        # the cache is donated, so the step's cache writes land in place
         if mesh is not None:
             self._step = jax.jit(
-                step_impl, out_shardings=(cache_shardings,
-                                          self.arr_shardings))
+                step_impl, donate_argnums=(1,),
+                out_shardings=(cache_shardings, self.arr_shardings))
             self._admit_scatter = jax.jit(
                 admit_impl, out_shardings=self.arr_shardings)
             self._deactivate = jax.jit(
                 _deactivate_scatter, out_shardings=self.arr_shardings)
         else:
-            self._step = jax.jit(step_impl)
+            self._step = jax.jit(step_impl, donate_argnums=(1,))
             self._admit_scatter = jax.jit(admit_impl)
             self._deactivate = jax.jit(_deactivate_scatter)
 

@@ -9,7 +9,8 @@ import jax.numpy as jnp
 import pytest
 
 from repro.serve.pages import (PageAllocator, PageOOM, apply_remap,
-                               dense_view, pages_needed, writeback)
+                               dense_view, insert_group, make_paged_cache,
+                               pages_needed, rows_at, writeback)
 
 try:
     from hypothesis import given, settings
@@ -159,14 +160,17 @@ def test_apply_remap_preserves_dense_view():
     a = alloc.alloc(2, "a")
     b = alloc.alloc(2, "b")
     alloc.free_owner("a")
-    pool = {"k": jnp.arange(n_pages * page, dtype=jnp.float32)
-            .reshape(1, n_pages, page)}
+    pool = {"k": jnp.arange(n_pages * page * 6, dtype=jnp.float32)
+            .reshape(1, n_pages, page, 6)}    # lane-dense rows of 2 x 3
     table_h = np.full((2, 2), n_pages, np.int32)
     table_h[0] = b                             # slot 0 owns b's pages
-    before = np.asarray(dense_view(pool, jnp.asarray(table_h), page)["k"])
+    before = np.asarray(
+        dense_view(pool, jnp.asarray(table_h), page, (2, 3))["k"])
+    assert before.shape == (1, 2, 2 * page, 2, 3)
     remap = alloc.compact()
     pool2, table2 = apply_remap(pool, table_h, remap, n_pages)
-    after = np.asarray(dense_view(pool2, jnp.asarray(table2), page)["k"])
+    after = np.asarray(
+        dense_view(pool2, jnp.asarray(table2), page, (2, 3))["k"])
     np.testing.assert_array_equal(before, after)
     assert (table2[1] == n_pages).all()        # sentinels stay sentinel
 
@@ -175,16 +179,58 @@ def test_writeback_drops_inactive_and_sentinel():
     """An inactive slot's pad-compute write and a sentinel table entry must
     both be DROPPED — a freed slot can never touch a re-owned page."""
     n_pages, page, B, S = 2, 4, 2, 8
-    pool = {"k": jnp.zeros((1, n_pages, page))}
+    pool = {"k": jnp.zeros((1, n_pages, page, 2))}
     table = jnp.full((B, S // page), n_pages, jnp.int32)
     table = table.at[0, 0].set(0)              # slot 0 owns page 0 only
-    dense = {"k": jnp.ones((1, B, S))}
+    dense = {"k": jnp.ones((1, B, S, 1, 2))}
     lengths = jnp.array([1, 1], jnp.int32)
-    out = writeback(pool, dense, table, lengths,
+    out = writeback(pool, rows_at(dense, lengths), table, lengths,
                     jnp.array([True, False]), page)
     got = np.asarray(out["k"])
-    assert got[0, 0, 1] == 1.0                 # active slot's write landed
-    assert got.sum() == 1.0                    # nothing else was touched
+    assert (got[0, 0, 1] == 1.0).all()         # active slot's write landed
+    assert got.sum() == 2.0                    # nothing else was touched
+
+
+def test_writeback_of_rows_lands_per_layer_and_drops_sentinel():
+    """The in-place decode's one scatter of the step's new rows (no dense
+    cache): layer l's row of slot b lands at
+    (layer l, table[b, len // page], len % page); an inactive slot and an
+    active slot whose page is a sentinel write nothing — not even into
+    another layer's pages."""
+    L, n_pages, page, B = 2, 3, 4, 3
+    pool = {"k": jnp.zeros((L, n_pages, page, 2))}
+    table = jnp.array([[2, 0], [1, n_pages], [n_pages, n_pages]], jnp.int32)
+    lengths = jnp.array([5, 6, 0], jnp.int32)
+    active = jnp.array([True, True, False])
+    rows = {"k": (jnp.arange(L * B, dtype=jnp.float32) + 1)
+            .reshape(L, B, 1, 1) * jnp.ones((L, B, 1, 2))}
+    got = np.asarray(writeback(pool, rows, table, lengths, active,
+                               page)["k"])
+    want = np.zeros((L, n_pages, page, 2), np.float32)
+    for l in range(L):
+        want[l, 0, 1] = l * B + 1              # slot 0: page table[0, 1]
+    np.testing.assert_array_equal(got, want)   # slot 1: sentinel page
+
+
+def test_lane_dense_pool_round_trips_a_prefill():
+    """Pools are lane-dense [lead, n_pages, page, KV*hd]; a prefill's
+    dense rows go in by a reshape and come back out of the dense view
+    unchanged, on whatever pages the table names."""
+    lead, B, S, KV, hd, page = 2, 2, 8, 2, 3, 4
+    template = {"k": jnp.zeros((lead, B, S, KV, hd)),
+                "conv": jnp.zeros((lead, B, 5))}
+    cache = make_paged_cache(template, 5, page, B)
+    assert cache["data"]["k"].shape == (lead, 5, page, KV * hd)
+    assert cache["data"]["conv"].shape == (lead, B, 5)
+    table = jnp.array([[3, 1], [0, 4]], jnp.int32)
+    mini = {"k": jnp.arange(lead * B * S * KV * hd, dtype=jnp.float32)
+            .reshape(lead, B, S, KV, hd),
+            "conv": jnp.ones((lead, B, 5))}
+    data = insert_group(cache["data"], mini, jnp.arange(B), table, page)
+    back = dense_view(data, table, page, (KV, hd))
+    np.testing.assert_array_equal(np.asarray(back["k"]),
+                                  np.asarray(mini["k"]))
+    np.testing.assert_array_equal(np.asarray(back["conv"]), 1.0)
 
 
 def test_pages_needed():

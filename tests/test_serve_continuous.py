@@ -22,9 +22,8 @@ def skewed(cfg, n, *, long_new=20, seed=0):
     return skewed_requests(cfg, n, seed=seed, long_new=long_new)
 
 
-@pytest.fixture(scope="module")
-def setup():
-    cfg = reduce_for_smoke(get_config("qwen1.5-0.5b"))
+def _build(arch):
+    cfg = reduce_for_smoke(get_config(arch))
     key = jax.random.key(0)
     params = init_lm(key, cfg)
     store = ProfileStore(cfg.num_layers, cfg.xpeft.num_adapters,
@@ -33,6 +32,11 @@ def setup():
     for pid in range(3):
         store.add_profile(pid, jax.tree.map(lambda t: t[pid], table))
     return cfg, params, store
+
+
+@pytest.fixture(scope="module")
+def setup():
+    return _build("qwen1.5-0.5b")
 
 
 def drain(setup, *, continuous, n=6, long_new=20, **kw):
@@ -157,3 +161,32 @@ def test_requeue_front_preserves_order():
     assert sched.stats()["requeued"] == 2
     again = sched.next_batch(2)
     assert [r.uid for r in again] == [r.uid for r in first]
+
+
+@pytest.mark.parametrize("case,route", [("full", "paged"),
+                                        ("spec", "dense_view"),
+                                        ("sliding", "dense_view"),
+                                        ("zamba", "dense_view"),
+                                        ("windowed", "dense")])
+def test_decode_route_follows_what_the_step_can_read(setup, case, route):
+    """Full causal attention reads KV pages in place; speculation, a
+    sliding-window mix and zamba's shared attention decode through the
+    dense view of the pages; the windowed engine has a dense cache.
+    serve_stats names the route the compiled step took and counts the
+    steps it ran."""
+    cfg, params, store = _build("zamba2-1.2b") if case == "zamba" else setup
+    if case == "spec":
+        cfg = cfg.with_(spec_enable=True, spec_gamma=2)
+    if case == "sliding":
+        cfg = cfg.with_(attn_type="sliding_mix", sliding_window=8,
+                        global_every=2)
+    eng = ServeEngine(cfg, params, store, max_slots=2, max_seq=64,
+                      sync_every=4, continuous=case != "windowed",
+                      page_size=16)
+    eng.run_until_drained(skewed(cfg, 3, long_new=6))
+    st = eng.serve_stats()
+    assert st["decode_route"] == route
+    assert st["steps_by_route"] == {route: st["device_steps"]}
+    assert st["device_steps"] > 0
+    eng.reset_stats()
+    assert eng.serve_stats()["steps_by_route"] == {route: 0}
